@@ -35,9 +35,15 @@ def make_backend(opts: Options) -> ClusterBackend:
         n_pods = int(env_read("KLOGS_FAKE_PODS", "6"))
         n_containers = int(env_read("KLOGS_FAKE_CONTAINERS", "2"))
         n_lines = int(env_read("KLOGS_FAKE_LINES", "300"))
+        # KLOGS_FAKE_EPOCH pins the clock the synthetic history ends at,
+        # so an oracle outside the process can rebuild every line.
+        kw = {}
+        if env_read("KLOGS_FAKE_EPOCH"):
+            epoch = float(env_read("KLOGS_FAKE_EPOCH"))
+            kw["clock"] = lambda: epoch
         fc = FakeCluster.synthetic(
-            n_pods=n_pods, n_containers=n_containers, lines_per_container=n_lines
-        )
+            n_pods=n_pods, n_containers=n_containers,
+            lines_per_container=n_lines, **kw)
         fc.add_namespace("kube-system")
         return fc
 
@@ -293,7 +299,13 @@ def make_pipeline_for(opts: Options, registry=None):
     from klogs_tpu.filters.sink import make_pipeline
 
     from klogs_tpu.filters.compiler.parser import RegexSyntaxError
+    from klogs_tpu.filters.tpu import DeviceUnavailable
 
+    if (opts.backend == "tpu" and opts.remote is None
+            and opts.resolver is None):
+        from klogs_tpu.utils.cache import enable_compile_cache
+
+        enable_compile_cache()
     try:
         return make_pipeline(opts.match, opts.backend, remote=opts.remote,
                              ignore_case=opts.ignore_case,
@@ -311,6 +323,8 @@ def make_pipeline_for(opts: Options, registry=None):
         term.fatal("unsupported --match/--exclude pattern: %s", e)
     except ImportError as e:
         term.fatal("--backend %s is unavailable: %s", opts.backend, e)
+    except DeviceUnavailable as e:
+        term.fatal("%s", e)
 
 
 def _write_stats_json(path: str, registry, pipeline) -> None:
@@ -350,6 +364,8 @@ def _write_stats_json(path: str, registry, pipeline) -> None:
                 "device_p90_s": s.percentile_device_s(90),
                 "device_p99_s": s.percentile_device_s(99),
             })
+        if s.device_doc is not None:
+            doc["device"] = s.device_doc()
     try:
         with open(path, "w") as f:
             json.dump(doc, f, indent=1)
@@ -683,7 +699,7 @@ async def _run_async_inner(
                             # through asyncio.run's cleanup, which can
                             # block on the very await that wedged the
                             # graceful path — e.g. backend.close on a
-                            # dead tunnel). Die by signal, like the
+                            # dead connection). Die by signal, like the
                             # default handler would.
                             signal.signal(signal.SIGINT, signal.SIG_DFL)
                             os.kill(os.getpid(), signal.SIGINT)

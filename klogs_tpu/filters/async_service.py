@@ -151,7 +151,7 @@ class AsyncFilterService:
         immediately, a decrease absorbs permits in the background as
         in-flight batches retire (work already dispatched is never
         cancelled). Values are trusted: the controller validates and
-        bounds them against the committed operating surface."""
+        bounds them against ops/tune.py OPERATING_SURFACE."""
         if coalesce_lines is not None:
             self._coalesce_lines = int(coalesce_lines)
         if max_in_flight is None:

@@ -73,6 +73,11 @@ class FilterStats:
         self._sweep_lines = r.family("klogs_sweep_lines_total")
         self._sweep_cand = r.family("klogs_sweep_candidate_lines_total")
         self._sweep_fallback = r.family("klogs_sweep_fallback_total")
+        # Every kernel degrade (a Mosaic compile or execution failure
+        # the engine survived on a plainer kernel), by the path that
+        # gave way: a run that degraded is not a run of the kernel it
+        # asked for, and chip_smoke.py fails on any of these.
+        self._engine_degrade = r.family("klogs_engine_degrade_total")
         # Degrade-policy visibility (--on-filter-error, resilience):
         # batches/lines that bypassed or skipped filtering because the
         # filter service was unavailable.
@@ -81,6 +86,10 @@ class FilterStats:
         self._degraded_lines = r.family(
             "klogs_filter_degraded_lines_total")
         self.pf_disabled_reason: str | None = None
+        # The device block (platform, kind, count, kernel) of the TPU
+        # engine feeding these stats — set by NFAEngineFilter, read at
+        # --stats-json dump time; None on the host (cpu) backend.
+        self.device_doc = None
         self.started_at = time.perf_counter()
         # Warmup boundary: timestamp when the FIRST batch started
         # filtering. lines_per_sec measures from here, not from pipeline
@@ -182,13 +191,18 @@ class FilterStats:
         self._sweep_lines.labels(path=path).inc(n_lines)
         self._sweep_cand.labels(path=path).inc(n_candidates)
 
-    def record_sweep_fallback(self) -> None:
-        """The device sweep degraded (build or kernel failure) and the
-        batch ran on the fallback path instead."""
-        self._sweep_fallback.inc()
-        from klogs_tpu.obs.trace import flight_trigger
+    def record_degrade(self, path: str) -> None:
+        """A device kernel gave way and the engine carried on with a
+        plainer one: ``path`` names what failed (sweep_build,
+        sweep_kernel, gated_kernel, chain, fetch). A device-sweep
+        degrade also counts on klogs_sweep_fallback_total, which the
+        host indexed engine's sweep shares."""
+        self._engine_degrade.labels(path=path).inc()
+        if path.startswith("sweep"):
+            self._sweep_fallback.inc()
+            from klogs_tpu.obs.trace import flight_trigger
 
-        flight_trigger("sweep-fallback")
+            flight_trigger("sweep-fallback")
 
     def record_queue_wait(self, wait_s: float) -> None:
         self._queue.observe(wait_s)

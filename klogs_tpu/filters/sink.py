@@ -447,20 +447,26 @@ def _build_filter(patterns: list[str], backend: str, stats,
             registry=stats.registry if stats is not None else None)[0]
     import jax
 
-    from klogs_tpu.filters.tpu import NFAEngineFilter
+    from klogs_tpu.filters.tpu import NFAEngineFilter, resolve_kernel
 
+    # Raises DeviceUnavailable when --backend tpu finds no TPU and the
+    # run did not ask for the CPU.
+    kernel = resolve_kernel()
     # Multi-chip: shard lines (data) x pattern groups over the mesh;
     # single chip: plain on-device batches, no collective overhead.
     engine = None
     if jax.device_count() > 1:
         from klogs_tpu.parallel.mesh import MeshEngine
 
-        # Real chips: per-shard Pallas kernel; virtual/CPU meshes:
-        # GSPMD over the jnp path (kernel needs Mosaic or interpret).
-        impl = "pallas" if jax.default_backend() != "cpu" else "gspmd"
-        engine = MeshEngine(patterns, ignore_case=ignore_case, impl=impl)
+        # The per-shard Pallas kernel on chips; GSPMD over the jnp path
+        # on a CPU mesh.
+        impl = {"pallas": "pallas", "interpret": "pallas_interpret",
+                "jnp": "gspmd"}[kernel]
+        engine = MeshEngine(
+            patterns, ignore_case=ignore_case, impl=impl,
+            on_degrade=stats.record_degrade if stats is not None else None)
     return NFAEngineFilter(patterns, ignore_case=ignore_case,
-                           engine=engine, stats=stats)
+                           engine=engine, kernel=kernel, stats=stats)
 
 
 def _env_positive_float(name: str, default: float) -> float:

@@ -147,10 +147,58 @@ def pack_lines(lines: list[bytes], width: int) -> tuple[np.ndarray, np.ndarray]:
     return batch, lengths
 
 
+KERNELS = ("pallas", "interpret", "jnp")
+
+
+class DeviceUnavailable(RuntimeError):
+    """--backend tpu found no TPU, and JAX_PLATFORMS did not ask for
+    the CPU."""
+
+
+def resolve_kernel(kernel: "str | None" = None) -> str:
+    """The kernel the TPU engine runs: ``kernel``, else KLOGS_TPU_KERNEL,
+    else ``auto`` — the Pallas kernel on an accelerator and the jnp
+    path on the CPU. The CPU is only ever a deliberate choice: when JAX
+    finds no accelerator and JAX_PLATFORMS does not name the CPU alone,
+    this raises DeviceUnavailable instead of carrying on at jnp speed."""
+    import jax
+
+    from klogs_tpu.utils.env import jax_cpu_requested
+
+    kernel = kernel or env_read("KLOGS_TPU_KERNEL", "auto")
+    if kernel != "auto" and kernel not in KERNELS:
+        raise ValueError(f"KLOGS_TPU_KERNEL={kernel!r}: expected auto, "
+                         + ", ".join(KERNELS))
+    platform = jax.default_backend()
+    if platform == "cpu" and not jax_cpu_requested():
+        raise DeviceUnavailable(
+            "--backend tpu needs a TPU, but JAX found none (its devices: "
+            f"{', '.join(str(d) for d in jax.devices())}); set "
+            "JAX_PLATFORMS=cpu to run the engine on the CPU on purpose")
+    if kernel == "auto":
+        kernel = "jnp" if platform == "cpu" else "pallas"
+    return kernel
+
+
+def device_doc(kernel: str) -> dict:
+    """The device block --stats-json, Hello and /profile carry: the
+    device as JAX reports it, how many, the kernel in use, whether the
+    native host packer loaded, and the compile clock once started."""
+    import jax
+
+    from klogs_tpu.native import hostops
+    from klogs_tpu.utils.cache import COMPILES
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "count": jax.device_count(), "kernel": kernel,
+            "native": hostops is not None, **COMPILES.doc()}
+
+
 class NFAEngineFilter(LogFilter):
-    """Batch-NFA filter on the JAX engine (TPU when available, else the
-    same code path on CPU — semantics are identical, per conftest's
-    hermetic setup)."""
+    """Batch-NFA filter on the JAX engine: the Pallas kernel on a TPU,
+    the jnp path on a CPU run that asked for it (identical semantics,
+    per conftest's hermetic setup)."""
 
     # Above this, a single line routes to the sequence-parallel scan
     # (ops/seqscan): the chunked vector path costs len/chunk_bytes
@@ -161,13 +209,18 @@ class NFAEngineFilter(LogFilter):
     def __init__(self, patterns: list[str], ignore_case: bool = False,
                  chunk_bytes: int = 4096, engine=None, kernel: str | None = None,
                  stats=None):
-        import jax
-
         from klogs_tpu.ops import nfa  # deferred: --backend=cpu must not need jax
 
         self._nfa = nfa
-        self._prog = compile_patterns(patterns, ignore_case=ignore_case)
-        self._dp = nfa.pack_program(self._prog)
+        self._patterns = list(patterns)
+        self._ignore_case = ignore_case
+        # The union automaton of all K patterns (_union) and its
+        # augmented long-line form (_long_program): built on first use,
+        # because at thousand-pattern K the union exceeds the position
+        # cap while the grouped kernel, which short lines run, does not.
+        self._prog = None
+        self._dp = None
+        self._dp_aug = None
         self._chunk_bytes = chunk_bytes
         self._engine = engine  # optional parallel engine (klogs_tpu.parallel)
         self._stats = stats  # optional FilterStats for engine visibility
@@ -183,17 +236,14 @@ class NFAEngineFilter(LogFilter):
         # operator can see shape churn (each event is a latency cliff).
         self._shapes_seen: set[tuple[int, int]] = set()
 
-        # Execution path for the hot op: the Pallas kernel on real TPU,
-        # the jnp/lax.scan path elsewhere (identical semantics; the
-        # kernel's Mosaic lowering needs TPU hardware). "interpret"
+        # Execution path for the hot op (resolve_kernel): the Pallas
+        # kernel on a TPU, jnp on a deliberate CPU run; "interpret"
         # exercises the kernel code hermetically (tests).
-        kernel = kernel or env_read("KLOGS_TPU_KERNEL", "auto")
-        if kernel == "auto":
-            kernel = "pallas" if jax.default_backend() not in ("cpu",) else "jnp"
+        kernel = resolve_kernel(kernel)
         self._kernel = kernel
+        if stats is not None:
+            stats.device_doc = self.device_doc
         if kernel in ("pallas", "interpret"):
-            import jax.numpy as jnp
-
             from klogs_tpu.ops import pallas_nfa
 
             self._pallas = pallas_nfa
@@ -205,10 +255,7 @@ class NFAEngineFilter(LogFilter):
             self._dp_grouped, self._g_live, self._g_acc = nfa.compile_grouped(
                 patterns, ignore_case=ignore_case
             )
-            aug = nfa.augment(self._prog)
-            self._dp_aug = nfa.pack_program(aug, dtype=jnp.int8)
-            self._live = self._prog.n_states
-            self._acc = self._prog.n_states + 1
+            self._match_all = self._dp_grouped.match_all
             # Host-side classification table for the grouped hot path
             # (pack_classify). Class ids ride int8, so a pattern set
             # whose shared classifier exceeds 127 classes (hundreds of
@@ -219,12 +266,6 @@ class NFAEngineFilter(LogFilter):
                     self._dp_grouped.byte_class).astype(np.int8)
             else:
                 self._cls_table = None
-            # Same for the augmented union program (long-line chunks).
-            if self._dp_aug.n_classes <= 127:
-                self._aug_cls_table = np.asarray(
-                    self._dp_aug.byte_class).astype(np.int8)
-            else:
-                self._aug_cls_table = None
             # Degrade memory for the DEFAULTED chain variant
             # (mask_block=4 on hardware): chain restructurings are
             # compile-fragile on unproven backends (mask_block=8/16
@@ -287,6 +328,8 @@ class NFAEngineFilter(LogFilter):
             if engine is None:
                 self._init_sweep(patterns, ignore_case)
         else:
+            # The jnp path runs every line on the union automaton.
+            self._match_all = self._union().match_all
             self._sweep_tables = None
             from klogs_tpu.filters.cpu import device_sweep_env
 
@@ -301,6 +344,43 @@ class NFAEngineFilter(LogFilter):
                     "KLOGS_TPU_SWEEP=1 ignored: the fused sweep needs "
                     "the pallas/interpret kernel (running %s)",
                     kernel)
+
+    def _union(self):
+        """The union automaton (and its packed form, self._dp)."""
+        if self._prog is None:
+            prog = compile_patterns(self._patterns,
+                                    ignore_case=self._ignore_case)
+            dp = self._nfa.pack_program(prog)
+            with self._state_lock:
+                self._prog, self._dp = prog, dp
+        return self._prog
+
+    def _long_program(self):
+        """The augmented union program that lines over chunk_bytes run
+        on (the chunk kernel and the seq-scan: state carried across
+        chunks needs one state space), with its live/accept state ids
+        and host classification table (None past int8 class ids)."""
+        if self._dp_aug is None:
+            import jax.numpy as jnp
+
+            from klogs_tpu.filters.compiler.parser import RegexSyntaxError
+
+            try:
+                prog = self._union()
+            except RegexSyntaxError as e:
+                raise RegexSyntaxError(
+                    f"a line over {self._chunk_bytes} bytes runs on the "
+                    f"union automaton of all {len(self._patterns)} "
+                    f"patterns, which this set cannot build: {e}") from e
+            dp_aug = self._nfa.pack_program(self._nfa.augment(prog),
+                                            dtype=jnp.int8)
+            table = (np.asarray(dp_aug.byte_class).astype(np.int8)
+                     if dp_aug.n_classes <= 127 else None)
+            with self._state_lock:
+                self._live, self._acc = prog.n_states, prog.n_states + 1
+                self._aug_cls_table = table
+                self._dp_aug = dp_aug
+        return self._dp_aug
 
     def _init_sweep(self, patterns: list[str], ignore_case: bool) -> None:
         """Build the device sweep tables when the auto rule (or
@@ -353,8 +433,20 @@ class NFAEngineFilter(LogFilter):
                 "device sweep build failed for this %d-pattern set "
                 "(%s: %s); running the plain kernel",
                 len(patterns), type(e).__name__, e)
-            if self._stats is not None:
-                self._stats.record_sweep_fallback()
+            self._degraded("sweep_build")
+
+    def device_doc(self) -> dict:
+        """device_doc() for this engine's kernel, plus the mesh a
+        multi-chip engine spans."""
+        doc = device_doc(self._kernel)
+        if self._engine is not None:
+            doc["mesh"] = self._engine.placement()
+        return doc
+
+    def _degraded(self, path: str) -> None:
+        """Count a kernel that gave way (FilterStats.record_degrade)."""
+        if self._stats is not None:
+            self._stats.record_degrade(path)
 
     def match_lines(self, lines: list[bytes]) -> list[bool]:
         return self.fetch(self.dispatch(lines))
@@ -415,7 +507,7 @@ class NFAEngineFilter(LogFilter):
         n = len(offsets) - 1
         if n == 0:
             return (0, [])
-        if self._prog.match_all:
+        if self._match_all:
             return (n, None)
         if (hostops is not None
                 and hasattr(hostops, "pack_classify_framed")
@@ -562,7 +654,7 @@ class NFAEngineFilter(LogFilter):
         (jax dispatch is asynchronous). Returns a handle for fetch()."""
         if not lines:
             return (0, [])
-        if self._prog.match_all:
+        if self._match_all:
             return (len(lines), None)  # all-match shortcut
         bodies = [ln.rstrip(b"\n") for ln in lines]  # parity with RegexFilter
         parts = []  # (index_list, device_mask_or_ndarray)
@@ -629,6 +721,7 @@ class NFAEngineFilter(LogFilter):
                 term.warning(
                     "device kernel failed at fetch (%s); "
                     "retrying on the plain path", str(e)[:120])
+                self._degraded("fetch")
                 vals = np.asarray(retry())
                 pf = None
             out[idxs] = vals[: len(idxs)]
@@ -664,12 +757,14 @@ class NFAEngineFilter(LogFilter):
                 # asynchronously: fetch() retries on the plain fn (whose
                 # own sync chain-degrade then covers a chain fault).
                 def retry(cls=cls):
+                    self._degraded("gated_kernel")
                     eng.disable_prefilter()
                     return eng.match_cls(cls, plain=True)
             elif getattr(eng, "_chain_defaulted", False):
                 # No gating, but the DEFAULTED chain variant can still
                 # fail asynchronously at fetch: degrade and rerun.
                 def retry(cls=cls):
+                    self._degraded("chain")
                     eng.degrade_chain()
                     return eng.match_cls(cls)
             try:
@@ -692,18 +787,19 @@ class NFAEngineFilter(LogFilter):
                 dpg, self._g_live, self._g_acc, cls,
                 interpret=interpret, **run_kw)
 
-        def chain_retry(record: bool = True):
+        def chain_retry():
             # Rerun without the chain restructure ONLY if the chain was
             # a default — an env-forced variant is kept even here (the
             # operator asked to measure exactly that kernel; if it is
             # the async fault the rerun fails again and raises loudly).
-            if record and chain_defaulted:
+            if chain_defaulted:
                 with self._state_lock:
                     self._chain_fallback = True
+                self._degraded("chain")
             return run_plain(dict(kw, mask_block=1) if chain_defaulted
                              else kw)
 
-        def pf_retry(record: bool = True):
+        def pf_retry():
             # Fetch-time failure of the PREFILTERED kernel: degrade one
             # cause at a time (ADVICE r4) — drop gating but KEEP the
             # defaulted chain variant (its +13% win is independent of
@@ -712,6 +808,7 @@ class NFAEngineFilter(LogFilter):
             # second async fault surfaces here, not at the caller.
             with self._state_lock:
                 self._pf_tables = None
+            self._degraded("gated_kernel")
             try:
                 return np.asarray(run_plain(kw))
             except Exception as e:
@@ -744,6 +841,7 @@ class NFAEngineFilter(LogFilter):
                     "falling back to plain NFA", str(e)[:120])
                 with self._state_lock:
                     self._pf_tables = None
+                self._degraded("gated_kernel")
         try:
             mask = run_plain(kw)
         except Exception as e:
@@ -781,42 +879,36 @@ class NFAEngineFilter(LogFilter):
         if self._engine is not None:
             eng = self._engine
             retry = None
-            swept_before = getattr(eng, "swept", False)
-            if swept_before:
+            if getattr(eng, "swept", False):
                 # Async failure of the fused sweep fn surfaces at
-                # fetch: drop the sweep, count the degrade (the mesh
-                # holds no stats handle), rerun on the classify path
-                # (whose own gated/chain degrades then apply).
+                # fetch: drop the sweep, count the degrade, rerun on
+                # the classify path (whose own gated/chain degrades
+                # then apply).
                 def retry(batch=batch, lengths=lengths):
+                    self._degraded("sweep_kernel")
                     eng.disable_sweep()
-                    if self._stats is not None:
-                        self._stats.record_sweep_fallback()
                     return eng.match_batch(batch, lengths)
             elif getattr(eng, "gated", False):
                 def retry(batch=batch, lengths=lengths):
+                    self._degraded("gated_kernel")
                     eng.disable_prefilter()
                     return eng.match_batch(batch, lengths)
             elif getattr(eng, "_chain_defaulted", False):
                 def retry(batch=batch, lengths=lengths):
+                    self._degraded("chain")
                     eng.degrade_chain()
                     return eng.match_batch(batch, lengths)
-            mask = eng.match_batch(batch, lengths)
-            if (swept_before and not getattr(eng, "swept", False)
-                    and self._stats is not None):
-                # The mesh degraded internally at dispatch (its own
-                # try/except warned already) — surface it on the
-                # wrapper's counter so klogs_sweep_fallback_total is
-                # the one place sweep degrades show.
-                self._stats.record_sweep_fallback()
-            return mask, retry, None
+            # A dispatch-time degrade inside the mesh counts through
+            # its own on_degrade hook (filters/sink.py wires it).
+            return eng.match_batch(batch, lengths), retry, None
         if self._kernel in ("pallas", "interpret"):
             interpret = self._kernel == "interpret"
             kw, chain_defaulted = self._chain_kwargs(interpret)
 
-            def plain_retry(record: bool = True):
-                if record:
-                    with self._state_lock:
-                        self._chain_fallback = True
+            def plain_retry():
+                with self._state_lock:
+                    self._chain_fallback = True
+                self._degraded("chain")
                 return self._pallas.match_batch_grouped_pallas(
                     self._dp_grouped, self._g_live, self._g_acc,
                     batch, lengths, interpret=interpret,
@@ -839,8 +931,7 @@ class NFAEngineFilter(LogFilter):
                     # here.
                     with self._state_lock:
                         self._sweep_tables = None
-                    if self._stats is not None:
-                        self._stats.record_sweep_fallback()
+                    self._degraded("sweep_kernel")
                     try:
                         return np.asarray(run_plain(kw))
                     except Exception:
@@ -866,8 +957,7 @@ class NFAEngineFilter(LogFilter):
                         "falling back to plain NFA", str(e)[:120])
                     with self._state_lock:
                         self._sweep_tables = None
-                    if self._stats is not None:
-                        self._stats.record_sweep_fallback()
+                    self._degraded("sweep_kernel")
             try:
                 mask = run_plain(kw)
             except Exception as e:
@@ -894,10 +984,12 @@ class NFAEngineFilter(LogFilter):
         n_chunks = int(np.ceil(total.max() / L))
         use_pallas = self._kernel in ("pallas", "interpret")
         if use_pallas:
-            v = self._pallas.initial_state_kernel(self._dp_aug, self._live, B)
+            v = self._pallas.initial_state_kernel(self._long_program(),
+                                                  self._live, B)
         else:
+            self._union()
             v, matched = self._nfa.initial_state(self._dp, B)
-        host_cls = use_pallas and getattr(self, "_aug_cls_table", None) is not None
+        host_cls = use_pallas and self._aug_cls_table is not None
         for k in range(n_chunks):
             seg = [b[k * L : (k + 1) * L].ljust(L, b"\0") for b in bodies]
             seg += [b"\0" * L] * pad_rows
@@ -935,18 +1027,11 @@ class NFAEngineFilter(LogFilter):
         dispatches. Concurrent jumbo lines advance together in one
         vmapped program per chunk-count bucket — no per-line dispatch
         or recompilation."""
-        import jax.numpy as jnp
-
         from klogs_tpu.ops import seqscan
 
-        if not hasattr(self, "_dp_seq"):
-            aug = self._nfa.augment(self._prog)
-            self._dp_seq = self._nfa.pack_program(aug, dtype=jnp.int8)
-            self._seq_live = self._prog.n_states
-            self._seq_acc = self._prog.n_states + 1
+        dp = self._long_program()
         return np.array(
-            seqscan.match_lines_scan(self._dp_seq, self._seq_live,
-                                     self._seq_acc, bodies),
+            seqscan.match_lines_scan(dp, self._live, self._acc, bodies),
             dtype=bool)
 
     def close(self) -> None:

@@ -75,6 +75,13 @@ def _build(c_src: str, so_path: str) -> bool:
             pass
 
 
+def build(so_path: str) -> bool:
+    """Compile ``_hostops.c`` into ``so_path`` now. chip_smoke.py builds
+    a fresh copy and pins it (KLOGS_NATIVE_SO), so that no untracked
+    .so left in a copied tree decides a chip run."""
+    return _build(_SRC, so_path)
+
+
 def _ensure_so() -> "str | None":
     """Path to an up-to-date compiled extension, building if needed.
     Preference order: next to the source (repo checkouts — mtime keeps

@@ -171,6 +171,11 @@ SPECS: dict[str, dict] = {
     "klogs_engine_compile_total": _m(
         "counter", "New (width, rows) batch geometries first seen by "
         "the engine — each is one jit trace/compile."),
+    "klogs_engine_degrade_total": _m(
+        "counter", "Device kernels that gave way at run time, the "
+        "engine carrying on with a plainer kernel, by the path that "
+        "failed: sweep_build, sweep_kernel, gated_kernel, chain, "
+        "fetch.", labels=("path",), bounds={"path": "enum"}),
     "klogs_engine_bucket_width_bytes": _m(
         "histogram", "Padded line-width bucket per dispatched "
         "sub-batch.", buckets=WIDTH_BUCKETS),

@@ -24,8 +24,7 @@ have?* This module closes that gap with two cooperating pieces:
   tenancy admission and produced verdicts), rolling rates over a short
   window, and a headroom estimate in [0, 1] combining the profiler's
   observed peak stage utilization with the admitted-rate-vs-envelope
-  ratio (``KLOGS_FLEET_CAPACITY_LPS``, falling back to the
-  OPERATING_POINT.json sweep's measured ceiling). The server
+  ratio (``KLOGS_FLEET_CAPACITY_LPS``). The server
   advertises all three through Hello so ``ShardedFilterClient``
   re-exports them per endpoint (``klogs_fleet_endpoint_*``) — the
   scrape an HPA consumes.
@@ -184,6 +183,7 @@ class PipelineProfiler:
         self._last_doc: "dict[str, Any] | None" = None
         self._probes: "dict[str, Callable[[], float]]" = {}
         self._capacity: "FleetCapacity | None" = None
+        self._device: "Callable[[], dict | None] | None" = None
         self._json_lock = threading.Lock()
         self._json_path: "str | None" = None
         self._registry: "Registry | None" = None
@@ -247,6 +247,12 @@ class PipelineProfiler:
         registry binding)."""
         self._capacity = capacity
 
+    def attach_device(self,
+                      device: "Callable[[], dict | None] | None") -> None:
+        """Attach filterd's device block (platform, kind, count,
+        kernel) so /profile says what the stages ran on."""
+        self._device = device
+
     def set_json_path(self, path: "str | None") -> None:
         """--profile-json PATH: append one JSON line per tick."""
         with self._json_lock:
@@ -284,6 +290,7 @@ class PipelineProfiler:
             self._probes = {}
             self._registry = None
             self._synced = {}
+        self._device = None
         with self._json_lock:
             self._json_path = None
         self._capacity = None
@@ -392,6 +399,8 @@ class PipelineProfiler:
         cap = self._capacity
         if cap is not None:
             doc["capacity"] = cap.doc()
+        if self._device is not None:
+            doc["device"] = self._device()
         with self._lock:
             self._last_doc = doc
         if io:
@@ -434,8 +443,11 @@ class PipelineProfiler:
         if doc is not None:
             return doc
         if not enabled:
-            return {"enabled": False, "stages": {}, "samples": {},
-                    "bottleneck": None}
+            doc = {"enabled": False, "stages": {}, "samples": {},
+                   "bottleneck": None}
+            if self._device is not None:
+                doc["device"] = self._device()
+            return doc
         # On-demand (no tick has run yet): this path serves the HTTP
         # handler ON the event loop — no JSONL append, no /proc reads.
         return self.tick(io=False) or {"enabled": False}
@@ -476,8 +488,7 @@ class FleetCapacity:
     - *headroom*: in [0, 1], by signal trust (see ``headroom()``):
       1 - admitted_rate / envelope when the operator calibrated one
       (KLOGS_FLEET_CAPACITY_LPS), else 1 - peak stage utilization
-      from the live profiler, else the committed OPERATING_POINT.json
-      ceiling as the rate envelope, else None (profiler off and no
+      from the live profiler, else None (profiler off and no
       envelope) — an advertised guess would be worse than silence.
     """
 
@@ -492,8 +503,6 @@ class FleetCapacity:
         self._hist: "deque[tuple[float, int, int]]" = deque(
             [(time.monotonic(), 0, 0)])
         self._envelope = envelope_lps
-        self._envelope_resolved = envelope_lps is not None
-        self._envelope_from_ctor = envelope_lps is not None
         self._profiler = profiler
         self._m_offered: Any = None
         self._m_admitted: Any = None
@@ -563,23 +572,13 @@ class FleetCapacity:
     # -- the signal ---------------------------------------------------
 
     def envelope_lps(self) -> "float | None":
-        """The rate envelope, in trust order: KLOGS_FLEET_CAPACITY_LPS
-        when set (the deployment's own calibration — an operator's
-        number beats any inference), else — only as the
-        better-than-nothing default for processes with no profiler
-        signal — the best measured lines/s from the committed
-        OPERATING_POINT.json sweep. ``trusted`` says which case this
-        is: the file's ceiling was measured on the sweep's hardware,
-        not necessarily THIS deployment's, so live utilization
-        outranks it (see headroom)."""
+        """The rate envelope: KLOGS_FLEET_CAPACITY_LPS when set (the
+        deployment's own calibration), else the constructor's, else
+        None. No rate is ever assumed from a record of another run."""
         from klogs_tpu.utils.env import is_set, positive_float
 
         if is_set("KLOGS_FLEET_CAPACITY_LPS"):
             return positive_float("KLOGS_FLEET_CAPACITY_LPS", 0.0)
-        if self._envelope_resolved:
-            return self._envelope
-        self._envelope_resolved = True
-        self._envelope = _operating_point_lps()
         return self._envelope
 
     def headroom(self) -> "float | None":
@@ -592,34 +591,19 @@ class FleetCapacity:
         2. Else the profiler's peak stage utilization, clamped at 1
            (utilization is concurrency-inclusive: 16 in-flight RPCs
            legitimately read >1, which means 'saturated', not '16x').
-        3. Else the committed OPERATING_POINT.json ceiling — measured
-           on the sweep's hardware, not necessarily this one's, so it
-           only stands in when no live signal exists at all.
-        4. None when nothing exists — an advertised guess would be
+        3. None when neither exists — an advertised guess would be
            worse than silence."""
-        from klogs_tpu.utils.env import is_set
-
-        explicit = (is_set("KLOGS_FLEET_CAPACITY_LPS")
-                    or (self._envelope_resolved
-                        and self._envelope is not None
-                        and self._envelope_from_ctor))
-        if explicit:
-            cap = self.envelope_lps()
-            if cap:
-                # Before the rolling window has aged (process just
-                # started) the observed rate is ~0 by definition — a
-                # fresh server advertises full rate-headroom.
-                _, admitted_lps = self.rates()
-                return max(0.0, min(1.0,
-                                    1.0 - (admitted_lps or 0.0) / cap))
+        cap = self.envelope_lps()
+        if cap:
+            # Before the rolling window has aged (process just
+            # started) the observed rate is ~0 by definition — a
+            # fresh server advertises full rate-headroom.
+            _, admitted_lps = self.rates()
+            return max(0.0, min(1.0, 1.0 - (admitted_lps or 0.0) / cap))
         prof = self._profiler if self._profiler is not None else PROFILER
         util = prof.max_utilization()
         if util is not None:
             return max(0.0, 1.0 - min(1.0, util))
-        cap = self.envelope_lps()
-        if cap:
-            _, admitted_lps = self.rates()
-            return max(0.0, min(1.0, 1.0 - (admitted_lps or 0.0) / cap))
         return None
 
     def doc(self) -> "dict[str, Any]":
@@ -639,30 +623,6 @@ class FleetCapacity:
                              if admitted_lps is not None else None),
             "headroom": head,
         }
-
-
-def _operating_point_lps() -> "float | None":
-    """Best measured lines/s across the committed operating-point
-    sweep (OPERATING_POINT.json at the repo root, when present — a
-    deployed package without it relies on KLOGS_FLEET_CAPACITY_LPS)."""
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        os.pardir, "OPERATING_POINT.json")
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        return None
-    best = 0.0
-    try:
-        for entry in doc:
-            for run in entry.get("runs", []):
-                lps = run.get("lps")
-                if isinstance(lps, (int, float)):
-                    best = max(best, float(lps))
-    except (TypeError, AttributeError):
-        return None
-    return best or None
 
 
 # Process-global profiler: what --profile-json, the /profile endpoint,

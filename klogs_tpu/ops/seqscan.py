@@ -223,11 +223,7 @@ def _sharded_fn(mesh, n_states: int):
     """Build (once per mesh, via the jit cache on the returned callable)
     the shard_map'd per-device chunked fold."""
     import jax.sharding as shd
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     P = shd.PartitionSpec
 
@@ -254,14 +250,9 @@ def _sharded_fn(mesh, n_states: int):
         m_total, _ = jax.lax.scan(fold2, eye, all_m)
         return m_total[None]  # [1, S, S] -> gathered to [D, S, S]
 
-    specs = dict(mesh=mesh,
-                 in_specs=(P(), P("seq")),
-                 out_specs=P("seq"))
-    try:
-        fn = shard_map(per_device, check_vma=False, **specs)
-    except TypeError:
-        fn = shard_map(per_device, check_rep=False, **specs)
-    return jax.jit(fn)
+    return jax.jit(shard_map(per_device, mesh=mesh,
+                             in_specs=(P(), P("seq")),
+                             out_specs=P("seq"), check_vma=False))
 
 
 # shard_map'd fold programs, keyed by (device ids, axis name, S) — NOT

@@ -198,12 +198,11 @@ def env_overrides() -> dict:
     return out
 
 
-# Measured hardware default (kernel-variant A/B 2026-07-31,
-# OPERATING_POINT.json "fused_ab"): mask_block=4 pulls each block's four
-# step masks (one-hot + char-mask matmul, state-independent work) off
-# the serial chain, measuring 9.64M lines/s vs 8.42M for the plain chain
-# at the 1M x 64-in-flight operating point on v5e (+13%; fused-groups
-# ties plain, mask_block=8/16 fail Mosaic compile on real hardware).
+# Hardware default chain: mask_block=4 pulls each block's four step
+# masks (one-hot + char-mask matmul, state-independent work) off the
+# serial chain. A pre-PR-3 kernel-variant A/B on v5e favoured it over
+# the plain chain; mask_block=8/16 failed to compile under Mosaic there.
+# Not measured on today's code.
 HW_DEFAULT_MASK_BLOCK = 4
 
 
@@ -258,9 +257,9 @@ def kernel_kwargs(on_hardware: bool) -> dict:
 # /profile signals (queue depth, in-flight occupancy, bottleneck).
 # It is deliberately conservative: bounded multiplicative steps,
 # consecutive-tick hysteresis with a cooldown after every move, and
-# hard floor/ceiling anchored to the committed OPERATING_POINT.json
-# surface. KLOGS_TUNE=off (the default) means the controller is never
-# constructed — fixed-flag behavior, byte-identical.
+# hard floor/ceiling anchored to OPERATING_SURFACE. KLOGS_TUNE=off
+# (the default) means the controller is never constructed —
+# fixed-flag behavior, byte-identical.
 
 DEFAULT_TUNE_INTERVAL_S = 5.0
 DEFAULT_TUNE_STEP = 0.5  # fractional step: up = x(1+step), down = /(1+step)
@@ -285,38 +284,15 @@ def tune_mode() -> str:
     return mode
 
 
-def operating_surface() -> "dict[str, tuple[int, int]]":
-    """Measured (min, max) per controller parameter from the committed
-    OPERATING_POINT.json batch x n_flight sweep — the hard envelope the
-    controller may roam. Empty dict when the file is absent (a deployed
-    package): bounds then collapse to the initial flag values, i.e. the
-    controller can hold but never move."""
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        os.pardir, "OPERATING_POINT.json")
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError):
-        return {}
-    batches: "list[int]" = []
-    flights: "list[int]" = []
-    try:
-        for entry in doc:
-            for run in entry.get("runs", []):
-                b, nf = run.get("batch"), run.get("n_flight")
-                if isinstance(b, int) and not isinstance(b, bool):
-                    batches.append(b)
-                if isinstance(nf, int) and not isinstance(nf, bool):
-                    flights.append(nf)
-    except (TypeError, AttributeError):
-        return {}
-    out: "dict[str, tuple[int, int]]" = {}
-    if batches:
-        out["coalesce_lines"] = (min(batches), max(batches))
-    if flights:
-        out["max_in_flight"] = (min(flights), max(flights))
-    return out
+# The controller's roaming range: the search range of the old
+# operating-point sweep (batches of 262,144 to 1,048,576 lines, 8 to 64
+# dispatches in flight). It bounds where the controller may look; it
+# is not a measured rate, and nothing in it was measured on today's
+# code.
+COALESCE_LINES_RANGE = (262_144, 1_048_576)
+MAX_IN_FLIGHT_RANGE = (8, 64)
+OPERATING_SURFACE = {"coalesce_lines": COALESCE_LINES_RANGE,
+                     "max_in_flight": MAX_IN_FLIGHT_RANGE}
 
 
 class AdaptiveController:
@@ -337,9 +313,9 @@ class AdaptiveController:
       hysteresis: a signal oscillating tick-to-tick moves nothing.
 
     Bounds per parameter are ``[min(initial, surface_min),
-    max(initial, surface_max)]`` from :func:`operating_surface` — the
-    controller can roam the measured envelope and can always return to
-    the operator's flags, but never invents an unmeasured regime.
+    max(initial, surface_max)]`` from ``OPERATING_SURFACE`` — the
+    controller can roam that search range and can always return to
+    the operator's flags, but never leaves both.
 
     ``service`` duck-types ``coalesce_lines`` / ``max_in_flight``
     read properties and ``apply_tuning(coalesce_lines=, max_in_flight=)``
@@ -375,7 +351,7 @@ class AdaptiveController:
             "coalesce_lines": int(service.coalesce_lines),
             "max_in_flight": int(service.max_in_flight),
         }
-        surf = operating_surface() if surface is None else surface
+        surf = OPERATING_SURFACE if surface is None else surface
         self.bounds: "dict[str, tuple[int, int]]" = {}
         for param, initial in self.values.items():
             lo, hi = surf.get(param, (initial, initial))
@@ -453,7 +429,7 @@ class AdaptiveController:
         # Pressure: the dispatch pipe is the wall (all slots busy AND
         # callers queued behind it), or groups overflow before the
         # kick (a full group's worth pending — bigger groups amortize
-        # the per-dispatch fixed cost, the OPERATING_POINT.json fit).
+        # the per-dispatch fixed cost).
         press_flight = used >= flight - 0.5 and depth > 0
         press_group = pending >= group
         idle = (used <= max(1.0, 0.25 * flight)

@@ -24,6 +24,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from klogs_tpu.filters.compiler.glushkov import compile_patterns
@@ -80,8 +81,14 @@ class MeshEngine:
 
     def __init__(self, patterns: list[str], ignore_case: bool = False,
                  devices=None, grid: tuple[int, int] | None = None,
-                 impl: str = "gspmd"):
+                 impl: str = "gspmd", on_degrade=None):
         devices = devices if devices is not None else jax.devices()
+        # on_degrade(path): called when a kernel gives way and the
+        # engine carries on with a plainer one (FilterStats.
+        # record_degrade's paths); None counts nothing.
+        self._on_degrade = on_degrade or (lambda path: None)
+        # Devices holding the last verdict array (placement()).
+        self._verdict_devices = 0
         if grid is None:
             grid = choose_grid(len(devices), len(patterns))
         d, g = grid
@@ -127,11 +134,6 @@ class MeshEngine:
                 out_shardings=NamedSharding(self.mesh, P("data")),
             )
         elif impl == "shard_map":
-            try:
-                from jax import shard_map  # jax >= 0.8
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
-
             def per_shard(dp_shard, batch_local, lengths_local):
                 # dp leaves arrive with a leading local group axis of 1.
                 local = jax.tree_util.tree_map(lambda x: x[0], dp_shard)
@@ -153,12 +155,9 @@ class MeshEngine:
             # which the varying-manual-axes checker flags as
             # unvarying-meets-varying; the pmax above establishes the
             # replication the out_spec needs, so the check is safely
-            # off. (Knob renamed check_rep -> check_vma in jax 0.8.)
-            try:
-                smapped = shard_map(per_shard, check_vma=False, **specs)
-            except TypeError:
-                smapped = shard_map(per_shard, check_rep=False, **specs)
-            self._fn = jax.jit(smapped)
+            # off.
+            self._fn = jax.jit(
+                shard_map(per_shard, check_vma=False, **specs))
         else:
             raise ValueError(f"unknown impl {impl!r}")
         self.impl = impl
@@ -273,6 +272,8 @@ class MeshEngine:
                               have_prefilter=pf_stacked is not None,
                               interpret=interpret) == "sweep":
             sweep_stacked = self._stack_sweeps(groups, ignore_case, dps, G)
+            if sweep_stacked is None:
+                self._on_degrade("sweep_build")
             if sweep_stacked is not None and pf_stacked is not None:
                 note_sweep_supersedes(mesh=True)
                 pf_stacked = None
@@ -297,11 +298,6 @@ class MeshEngine:
         vkw.setdefault("tile_b", 2048)
         self._vkw = vkw
 
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
-
         def build(with_pf: bool, vkw=vkw):
             def per_shard(dp_shard, cls_local, *pf_shard):
                 local = jax.tree_util.tree_map(lambda x: x[0], dp_shard)
@@ -319,12 +315,9 @@ class MeshEngine:
             ]
             if with_pf:
                 in_specs.extend(P("pattern") for _ in pf_stacked)
-            specs = dict(mesh=self.mesh, in_specs=tuple(in_specs),
-                         out_specs=P("data"))
-            try:
-                smapped = shard_map(per_shard, check_vma=False, **specs)
-            except TypeError:
-                smapped = shard_map(per_shard, check_rep=False, **specs)
+            smapped = shard_map(per_shard, mesh=self.mesh,
+                                in_specs=tuple(in_specs),
+                                out_specs=P("data"), check_vma=False)
             if with_pf:
                 return jax.jit(
                     lambda dp, cls, pf=pf_stacked: smapped(dp, cls, *pf))
@@ -355,10 +348,7 @@ class MeshEngine:
                 ),
                 out_specs=P("data"),
             )
-            try:
-                smapped = shard_map(per_shard, check_vma=False, **specs)
-            except TypeError:
-                smapped = shard_map(per_shard, check_rep=False, **specs)
+            smapped = shard_map(per_shard, check_vma=False, **specs)
             return jax.jit(
                 lambda dp, batch, lengths, st=sweep_stacked:
                 smapped(dp, batch, lengths, st))
@@ -487,6 +477,7 @@ class MeshEngine:
                         "mesh fused sweep kernel unavailable (%s); "
                         "falling back to host classify + plain NFA",
                         str(e)[:120])
+                    self._on_degrade("sweep_kernel")
                     self.disable_sweep()
             from klogs_tpu.filters.tpu import classify_batch
 
@@ -504,8 +495,9 @@ class MeshEngine:
             lengths = np.concatenate(
                 [lengths, np.zeros((Bp - B,), dtype=lengths.dtype)]
             )
-        return self._fn(self.dp, self._place_data(batch, P("data", None)),
-                        self._place_data(lengths, P("data")))
+        return self._placed(
+            self._fn(self.dp, self._place_data(batch, P("data", None)),
+                     self._place_data(lengths, P("data"))))
 
     def _match_batch_swept(self, batch: np.ndarray, lengths: np.ndarray):
         """Fused byte path: [B, L] u8 + [B] i32 -> [>=B] bool device
@@ -526,11 +518,11 @@ class MeshEngine:
         with trace.TRACER.span("mesh.dispatch", impl=self.impl,
                                rows=Bp, swept=True,
                                grid=f"{self.grid[0]}x{self.grid[1]}"):
-            return self._fn_sweep(
+            return self._placed(self._fn_sweep(
                 self.dp, self._place_data(batch, P("data", None)),
                 self._place_data(np.ascontiguousarray(lengths,
                                                       dtype=np.int32),
-                                 P("data")))
+                                 P("data"))))
 
     def match_cls(self, cls: np.ndarray, plain: bool = False):
         """Hot-path entry for pallas impls: [B, T] int8/int32 class ids
@@ -555,7 +547,7 @@ class MeshEngine:
             with trace.TRACER.span("mesh.dispatch", impl=self.impl,
                                    rows=Bp, gated=use_gated,
                                    grid=f"{self.grid[0]}x{self.grid[1]}"):
-                return fn(self.dp, cls)
+                return self._placed(fn(self.dp, cls))
         except Exception as e:
             # Chain-variant compile fragility is a known failure mode
             # (mask_block=8/16 fail Mosaic on v5e). A DEFAULTED variant
@@ -575,8 +567,9 @@ class MeshEngine:
                 "default mask_block=%d chain failed on this backend (%s); "
                 "rebuilding with the plain chain",
                 self._vkw.get("mask_block"), str(e)[:120])
+            self._on_degrade("chain")
             self.degrade_chain()
-            return self._fn(self.dp, cls)
+            return self._placed(self._fn(self.dp, cls))
 
     def degrade_chain(self) -> None:
         """Rebuild both fns on the plain serial chain (mask_block=1) —
@@ -588,6 +581,21 @@ class MeshEngine:
         self._fn = self._build(False, self._vkw)
         if self.gated:
             self._fn_gated = self._build(True, self._vkw)
+
+    def _placed(self, verdicts):
+        """Note how many devices hold a verdict array (its sharding is
+        known at dispatch; no wait for the device)."""
+        self._verdict_devices = len(verdicts.sharding.device_set)
+        return verdicts
+
+    def placement(self) -> dict:
+        """The mesh as it ran: (data, pattern) shape, the devices it
+        spans, and how many devices held the last verdict array — the
+        proof that verdicts were sharded over every chip, not computed
+        on the first one."""
+        return {"shape": list(self.grid), "impl": self.impl,
+                "devices": int(self.mesh.devices.size),
+                "verdict_devices": self._verdict_devices}
 
     def close(self) -> None:
         pass

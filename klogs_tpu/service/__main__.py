@@ -5,6 +5,7 @@ import asyncio
 import sys
 
 from klogs_tpu.filters.compiler.parser import RegexSyntaxError
+from klogs_tpu.filters.tpu import DeviceUnavailable
 from klogs_tpu.service.server import serve
 
 
@@ -85,6 +86,10 @@ def main() -> None:
                     ap.error(f"--auth-token-file {ns.auth_token_file} is empty")
         except OSError as e:
             ap.error(f"cannot read --auth-token-file: {e}")
+    if ns.backend == "tpu":
+        from klogs_tpu.utils.cache import enable_compile_cache
+
+        enable_compile_cache()
     try:
         asyncio.run(serve(ns.match, ns.backend, ns.host, ns.port,
                           ignore_case=ns.ignore_case,
@@ -99,6 +104,9 @@ def main() -> None:
                           profile_json=ns.profile_json))
     except KeyboardInterrupt:
         pass
+    except DeviceUnavailable as e:
+        print(f"klogs-filterd: {e}", file=sys.stderr)
+        raise SystemExit(1)
     except RegexSyntaxError as e:  # subclasses ValueError: catch first
         print(f"unsupported --match pattern: {e}", file=sys.stderr)
         raise SystemExit(1)

@@ -41,7 +41,7 @@ from klogs_tpu.version import BUILD_VERSION
 def _make_filter(patterns: list[str], backend: str,
                  ignore_case: bool = False,
                  exclude: "list[str] | None" = None,
-                 stats=None):
+                 stats=None, kernel: "str | None" = None):
     from klogs_tpu.filters.base import build_include_exclude
 
     made = []
@@ -62,6 +62,7 @@ def _make_filter(patterns: list[str], backend: str,
             # Stats ride the first-built side only (≙ make_pipeline's
             # rule: feeding both combiner inputs would double-count).
             f = NFAEngineFilter(pats, ignore_case=ignore_case,
+                                kernel=kernel,
                                 stats=stats if not made else None)
         made.append(f)
         return f
@@ -143,6 +144,18 @@ class FilterServer:
         if not self.patterns and not self.exclude and not multi_set:
             raise ValueError("need at least one --match or --exclude pattern")
         self.backend = backend
+        # The kernel the TPU engines run, resolved once up front: a
+        # filterd that finds no TPU exits at start (DeviceUnavailable)
+        # instead of serving at jnp speed. None on the host backend.
+        self.kernel = None
+        self._device_doc = None
+        if backend == "tpu":
+            import functools
+
+            from klogs_tpu.filters.tpu import device_doc, resolve_kernel
+
+            self.kernel = resolve_kernel()
+            self._device_doc = functools.partial(device_doc, self.kernel)
         self.host = host
         self.port = port
         self.ignore_case = ignore_case
@@ -206,6 +219,9 @@ class FilterServer:
         # tracer's registry binding above).
         self.capacity = FleetCapacity(registry=self.registry)
         PROFILER.attach_capacity(self.capacity)
+        # Not a bound method: the process-global profiler must not keep
+        # this server alive past its run.
+        PROFILER.attach_device(self._device_doc)
         # Multi-tenant registry (docs/TENANCY.md): content-addressed
         # pattern sets behind weighted-fair admission; the startup set
         # (when present) is adopted as a pinned default lane so legacy
@@ -225,7 +241,8 @@ class FilterServer:
                 # sets too, not just the startup default — per-set
                 # attribution rides the klogs_tenant_* families.
                 return _make_filter(pats, self.backend, ignore_case=ic,
-                                    exclude=excl, stats=self._stats)
+                                    exclude=excl, stats=self._stats,
+                                    kernel=self.kernel)
 
             self.tenants = PatternSetRegistry(
                 factory, stats=self._stats,
@@ -244,7 +261,8 @@ class FilterServer:
             self._filter = _make_filter(patterns, backend,
                                         ignore_case=ignore_case,
                                         exclude=self.exclude,
-                                        stats=self._stats)
+                                        stats=self._stats,
+                                        kernel=self.kernel)
             shared = ({} if self.tenants is None
                       else dict(executor=self.tenants.executor,
                                 in_flight=self.tenants.in_flight))
@@ -275,6 +293,12 @@ class FilterServer:
                 not e.pinned and _uses_device_sweep(e.service._filter)
                 for e in self.tenants.entries())
         return False
+
+    @property
+    def device(self) -> "dict | None":
+        """The device block (filters.tpu.device_doc) Hello and /profile
+        carry; None on the host backend."""
+        return self._device_doc() if self._device_doc is not None else None
 
     @property
     def auth_enabled(self) -> bool:
@@ -432,6 +456,7 @@ class FilterServer:
             # gating this server's kernel (thousand-pattern mode).
             # Old clients ignore the key.
             "device_sweep": self.device_sweep,
+            "device": self.device,
         })
 
     async def _hello_multi(self, request: bytes) -> bytes:
@@ -473,6 +498,7 @@ class FilterServer:
             "metrics_port": self.metrics_port,
             "metrics_host": self.metrics_host,
             "device_sweep": self.device_sweep,
+            "device": self.device,
             # Registry mode: the client should Register its set (once)
             # and tag match RPCs with the returned id. "sets" is the
             # live registered count (banner/fleet debugging).

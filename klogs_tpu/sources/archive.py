@@ -16,7 +16,7 @@ replay oldest-first, so backfill output ordering matches what a live
 follow of the same file would have produced (the byte-parity
 acceptance test).
 
-Error taxonomy: a gzip member that ends mid-stream raises
+Error classes: a gzip member that ends mid-stream raises
 ``SourceError`` naming the archive path and the compressed byte offset
 where decoding died — never a raw EOFError; corrupt bytes raise the
 same with the zlib detail. zstd needs the ``zstandard`` package and is

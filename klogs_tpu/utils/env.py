@@ -46,6 +46,14 @@ def is_set(name: str) -> bool:
     return os.environ.get(name) is not None
 
 
+def jax_cpu_requested() -> bool:
+    """JAX_PLATFORMS names the CPU and nothing else: how the tests and
+    the CPU rehearsals say that a CPU run is what they mean. Anything
+    else asks for the accelerator, which --backend tpu then requires."""
+    raw = os.environ.get("JAX_PLATFORMS", "")
+    return {p.strip() for p in raw.split(",") if p.strip()} == {"cpu"}
+
+
 def positive_float(name: str, default: float,
                    exc: type = ValueError) -> float:
     """Strict positive finite float; zero/negative/nan/inf/garbage

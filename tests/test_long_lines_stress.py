@@ -160,6 +160,7 @@ def test_long_lines_host_cls_path_vs_oracle():
 
     pats = ["needle[0-9]x", "END$"]
     f = NFAEngineFilter(pats, chunk_bytes=256, kernel="interpret")
+    f._long_program()
     assert f._aug_cls_table is not None
     rng = random.Random(13)
     lines = []

@@ -80,6 +80,33 @@ def test_stats_summary_printed(tmp_path, capsys):
     assert "lines/sec" in out
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_stats_json_device_block(tmp_path, backend):
+    """--stats-json names the device a --backend tpu run filtered on
+    (here the suite's deliberate CPU run, on the jnp kernel); the host
+    backend has no device block."""
+    import json
+
+    import jax
+
+    stats = tmp_path / "stats.json"
+    rc = run_app(
+        ["-n", "default", "-a", "-p", str(tmp_path / "logs"),
+         "--match", "INFO", "--backend", backend,
+         "--stats-json", str(stats)],
+        make_cluster(),
+    )
+    assert rc == 0
+    doc = json.loads(stats.read_text())
+    if backend == "cpu":
+        assert "device" not in doc
+        return
+    dev = doc["device"]
+    assert (dev["platform"], dev["kernel"]) == ("cpu", "jnp")
+    assert dev["count"] == jax.device_count()
+    assert dev["device_kind"] == jax.devices()[0].device_kind
+
+
 def test_multiple_match_patterns_union(tmp_path):
     out_dir = str(tmp_path / "logs")
     rc = run_app(

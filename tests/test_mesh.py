@@ -234,3 +234,51 @@ def test_mesh_drops_fused_loudly_and_reapplies_default(monkeypatch, capsys):
     eng2 = MeshEngine(["ERROR"], grid=(4, 2), impl="pallas_interpret")
     assert "no mesh per-shard variant" in capsys.readouterr().out
     assert "mask_block" not in eng2._vkw
+
+
+@pytest.mark.parametrize("path", ["chain", "sweep_kernel", "sweep_build"])
+def test_mesh_degrades_report_through_on_degrade(monkeypatch, path):
+    """A kernel that gives way inside the mesh is reported by path to
+    the on_degrade hook (filters/sink.py wires it to the stats), once,
+    and the verdicts stay right."""
+    import klogs_tpu.ops.pallas_nfa as pallas_nfa
+    import klogs_tpu.ops.tune as tune
+    from klogs_tpu.ops import sweep as sweep_mod
+
+    pats = ["ERROR", r"WARN.*\d", "svc-[0-9]+ down", "timeout$"]
+    lines = [b"ERROR x", b"fine", b"WARN 42", b"svc-7 down", b"a timeout"]
+    if path == "chain":
+        monkeypatch.setattr(
+            tune, "chain_selection",
+            lambda on_hardware, allow_fused=True: ({"mask_block": 4}, True,
+                                                   False))
+        real = pallas_nfa.match_cls_grouped_pallas
+
+        def fragile(*a, **kw):
+            if kw.get("mask_block", 1) > 1:
+                raise RuntimeError("Mosaic rejected the chain")
+            return real(*a, **kw)
+        monkeypatch.setattr(pallas_nfa, "match_cls_grouped_pallas", fragile)
+    else:
+        monkeypatch.setenv("KLOGS_TPU_SWEEP", "1")
+    if path == "sweep_kernel":
+        real_b = pallas_nfa.match_batch_grouped_pallas
+
+        def boom(*a, **kw):
+            if kw.get("sweep_tables") is not None:
+                raise RuntimeError("injected sweep fault")
+            return real_b(*a, **kw)
+        monkeypatch.setattr(pallas_nfa, "match_batch_grouped_pallas", boom)
+    elif path == "sweep_build":
+        def boom_build(progs):
+            raise RuntimeError("injected build fault")
+        monkeypatch.setattr(sweep_mod, "stack_sweep_tables", boom_build)
+    seen = []
+    eng = MeshEngine(pats, grid=(4, 2), impl="pallas_interpret",
+                     on_degrade=seen.append)
+    f = NFAEngineFilter(pats, engine=eng, kernel="interpret")
+    want = RegexFilter(pats).match_lines(lines)
+    assert f.match_lines(lines) == want
+    assert f.match_lines(lines) == want
+    assert seen == [path]
+    assert eng.placement()["verdict_devices"] == 8

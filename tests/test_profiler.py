@@ -325,23 +325,22 @@ def test_capacity_envelope_validation(monkeypatch):
         FleetCapacity().envelope_lps()
 
 
-def test_headroom_live_utilization_outranks_file_envelope(monkeypatch):
-    """Review regression: the committed OPERATING_POINT ceiling was
-    measured on the sweep's hardware, not necessarily this
-    deployment's — a saturated stage observed by the LIVE profiler
-    must win over a rosy rate-vs-file-envelope estimate, or the HPA
-    never scales a cpu filterd whose implied envelope is the TPU
-    sweep's 8.5M lines/s."""
+def test_headroom_without_envelope_reads_live_utilization(monkeypatch):
+    """With no calibrated envelope, headroom comes from the live
+    profiler alone: None before any signal (no rate is ever assumed
+    from a record of another run), 0.0 once a stage is saturated, and
+    an explicit operator calibration still outranks utilization."""
     monkeypatch.delenv("KLOGS_FLEET_CAPACITY_LPS", raising=False)
     prof = PipelineProfiler()
+    cap = FleetCapacity(profiler=prof)
+    assert cap.envelope_lps() is None
+    assert cap.headroom() is None
     prof.enable(1.0)
     prof.tick()
     prof.on_span(_span_doc("device.fetch", 10.0))  # saturated
     time.sleep(0.05)
     prof.tick()
-    cap = FleetCapacity(profiler=prof)  # file envelope would say ~1.0
     assert cap.headroom() == 0.0
-    # An explicit operator calibration still outranks utilization.
     monkeypatch.setenv("KLOGS_FLEET_CAPACITY_LPS", "1000000")
     assert cap.headroom() == 1.0
     prof.reset()

@@ -2,6 +2,7 @@
 through --remote against FakeCluster."""
 
 import asyncio
+import json
 import os
 
 import pytest
@@ -529,3 +530,36 @@ class TestServeTeardown:
 
         asyncio.run(asyncio.wait_for(scenario(), timeout=10))
         assert [s.stops for s in made] == [1]
+
+
+def test_tpu_backend_hello_and_profile_carry_the_device_block():
+    """filterd --backend tpu says what it runs on, in Hello and on
+    /profile: the device as JAX reports it and the kernel in use (here
+    the deliberate CPU run of the test suite: jnp)."""
+    import jax
+
+    from klogs_tpu.obs import Registry
+    from klogs_tpu.obs.profiler import PROFILER
+
+    async def run():
+        server = FilterServer(["INFO"], backend="tpu", port=0,
+                              metrics_port=0, registry=Registry())
+        port = await server.start()
+        c = RemoteFilterClient(f"127.0.0.1:{port}")
+        try:
+            info = await c.hello()
+            status, body = await _http_get(server.metrics_port, "/profile")
+            return info["device"], status, json.loads(body)
+        finally:
+            await c.aclose()
+            await server.stop()
+
+    try:
+        hello_dev, status, prof = asyncio.run(run())
+    finally:
+        PROFILER.reset()
+    assert status == 200
+    for dev in (hello_dev, prof["device"]):
+        assert dev["platform"] == jax.devices()[0].platform == "cpu"
+        assert dev["count"] == jax.device_count()
+        assert dev["kernel"] == "jnp"

@@ -2,7 +2,7 @@
 
 Covers the PR 18 source abstraction end to end: replay rotation/
 truncation/resume semantics, archive decompression framing parity
-against a line-by-line oracle, the named-error taxonomy for damaged
+against a line-by-line oracle, the named-error classes for damaged
 archives, socket backpressure-by-construction, ClusterSource
 conformance (the kube path is byte-identical through the adapter),
 chaos source.read faults absorbed by the shared reconnect policy, and

@@ -392,14 +392,16 @@ def test_single_set_hello_stays_byte_identical():
         try:
             info = await c.hello()
             # Registry keys (multi_set/sets/set/registered) must not
-            # leak into the single-set handshake; the capacity trio is
-            # advertised in BOTH modes by design (fleet telemetry).
+            # leak into the single-set handshake; the capacity trio and
+            # the device block are advertised in BOTH modes by design
+            # (fleet telemetry; what the engine runs on).
             assert set(info) == {"patterns", "exclude", "ignore_case",
                                  "backend", "version", "framed",
                                  "metrics_port", "metrics_host",
                                  "device_sweep", "headroom",
                                  "fleet_offered_lines",
-                                 "fleet_admitted_lines"}
+                                 "fleet_admitted_lines", "device"}
+            assert info["device"] is None  # the host backend
         finally:
             await c.aclose()
             await server.stop()

@@ -269,6 +269,141 @@ def test_defaulted_chain_variant_degrades_to_plain(monkeypatch, capsys):
     assert seen[-1] == 1
 
 
+@pytest.mark.parametrize("platforms,want", [
+    ("cpu", "jnp"), (" cpu ", "jnp"), ("", None), ("tpu", None),
+    ("cpu,tpu", None)])
+def test_resolve_kernel_refuses_an_unasked_cpu(monkeypatch, platforms, want):
+    """kernel=auto runs jnp on the CPU only when JAX_PLATFORMS names the
+    CPU alone; otherwise a CPU backend means the TPU is missing."""
+    import jax
+
+    from klogs_tpu.filters.tpu import DeviceUnavailable, resolve_kernel
+
+    jax.devices()  # the backend is the CPU before the variable changes
+    monkeypatch.delenv("KLOGS_TPU_KERNEL", raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    if want is None:
+        with pytest.raises(DeviceUnavailable, match="needs a TPU"):
+            resolve_kernel()
+    else:
+        assert resolve_kernel() == want
+    monkeypatch.setenv("KLOGS_TPU_KERNEL", "mosaic")
+    with pytest.raises(ValueError, match="KLOGS_TPU_KERNEL"):
+        resolve_kernel()
+
+
+@pytest.mark.parametrize("entry", ["collector", "filterd"])
+def test_backend_tpu_exits_without_a_tpu(monkeypatch, tmp_path, capsys,
+                                         entry):
+    """--backend tpu on a CPU backend, JAX_PLATFORMS not asking for the
+    CPU: both entry points exit non-zero and name the missing device
+    instead of carrying on at jnp speed."""
+    import sys
+
+    import jax
+
+    from klogs_tpu.utils import cache
+
+    jax.devices()
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    monkeypatch.setattr(cache, "enable_compile_cache", lambda: None)
+    if entry == "collector":
+        from klogs_tpu.cli import main
+
+        rc = main(["-a", "--cluster", "fake", "--match", "ERROR",
+                   "--backend", "tpu", "-p", str(tmp_path)])
+    else:
+        from klogs_tpu.service.__main__ import main
+
+        monkeypatch.setattr(sys, "argv", ["klogs-filterd", "--match",
+                                          "ERROR", "--port", "0"])
+        with pytest.raises(SystemExit) as exc:
+            main()
+        rc = exc.value.code
+    assert rc == 1
+    out = capsys.readouterr()
+    assert "needs a TPU, but JAX found none" in out.out + out.err
+
+
+def _fragile_grouped(real, fail_when):
+    def fragile(*args, **kw):
+        if fail_when(kw):
+            raise RuntimeError("Mosaic rejected the kernel")
+        return real(*args, **kw)
+    return fragile
+
+
+class _FailsAtFetch:
+    """A device result whose execution fails when it is fetched."""
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("device execution failed")
+
+
+@pytest.mark.parametrize("path", ["sweep_build", "sweep_kernel",
+                                  "gated_kernel", "chain", "fetch"])
+def test_every_degrade_path_counts(monkeypatch, path):
+    """Each kernel that gives way bumps klogs_engine_degrade_total
+    under its own path label (fetch: an asynchronous failure, counted
+    beside the path its rerun degraded), and the verdicts stay right."""
+    import klogs_tpu.ops.pallas_nfa as pallas_nfa
+    import klogs_tpu.ops.tune as tune
+    from klogs_tpu.filters.base import FilterStats
+    from klogs_tpu.obs.metrics import Registry
+    from klogs_tpu.ops import sweep as sweep_mod
+
+    pats = ["ERROR 00[0-9]7", "WARN disk", "user=[a-z]+ failed"]
+    lines = [b"x ERROR 0007 boom", b"nothing", b"WARN disk full",
+             b"user=bob failed", b""]
+    want = RegexFilter(pats).match_lines(lines)
+    expect = {path: 1}
+    if path in ("sweep_build", "sweep_kernel"):
+        monkeypatch.setenv("KLOGS_TPU_SWEEP", "1")
+    if path == "sweep_build":
+        def boom(prog):
+            raise RuntimeError("injected build fault")
+        monkeypatch.setattr(sweep_mod, "device_sweep_tables", boom)
+    elif path == "sweep_kernel":
+        monkeypatch.setattr(pallas_nfa, "match_batch_grouped_pallas",
+                            _fragile_grouped(
+                                pallas_nfa.match_batch_grouped_pallas,
+                                lambda kw: kw.get("sweep_tables") is not None))
+    elif path == "gated_kernel":
+        monkeypatch.setenv("KLOGS_TPU_PREFILTER", "1")
+        monkeypatch.setattr(pallas_nfa, "match_cls_grouped_pallas",
+                            _fragile_grouped(
+                                pallas_nfa.match_cls_grouped_pallas,
+                                lambda kw: kw.get("prefilter_tables")
+                                is not None))
+    else:
+        # The hardware default chain (mask_block=4) on the interpreter.
+        monkeypatch.setattr(
+            tune, "chain_selection",
+            lambda on_hardware, allow_fused=True: ({"mask_block": 4}, True,
+                                                   False))
+        real = pallas_nfa.match_cls_grouped_pallas
+        if path == "chain":
+            fake = _fragile_grouped(real, lambda kw: kw["mask_block"] > 1)
+        else:
+            expect["chain"] = 1
+
+            def fake(*args, **kw):
+                if kw["mask_block"] > 1:
+                    return _FailsAtFetch()
+                return real(*args, **kw)
+        monkeypatch.setattr(pallas_nfa, "match_cls_grouped_pallas", fake)
+    reg = Registry()
+    f = NFAEngineFilter(pats, kernel="interpret",
+                        stats=FilterStats(registry=reg))
+    assert f.match_lines(lines) == want
+    assert f.match_lines(lines) == want  # degraded once, not per batch
+    fam = reg.family("klogs_engine_degrade_total")
+    got = {lv[0]: c.value for lv, c in fam.children() if c.value}
+    assert got == expect
+    swept = reg.family("klogs_sweep_fallback_total").value
+    assert swept == (1 if path.startswith("sweep") else 0)
+
+
 def test_env_forced_chain_variant_stays_loud(monkeypatch):
     """An operator-forced variant must fail loudly, not silently run a
     different kernel (the pick-by-measurement rule)."""

@@ -174,12 +174,17 @@ def _step(ctrl, doc):
     return asyncio.run(ctrl.step_once(doc))
 
 
-def test_operating_surface_reads_committed_sweep():
-    from klogs_tpu.ops.tune import operating_surface
+def test_operating_surface_is_the_old_sweeps_search_range():
+    from klogs_tpu.ops.tune import (
+        COALESCE_LINES_RANGE,
+        MAX_IN_FLIGHT_RANGE,
+        OPERATING_SURFACE,
+    )
 
-    surf = operating_surface()
-    assert surf["coalesce_lines"] == (262144, 1048576)
-    assert surf["max_in_flight"] == (8, 64)
+    assert COALESCE_LINES_RANGE == (262144, 1048576)
+    assert MAX_IN_FLIGHT_RANGE == (8, 64)
+    assert OPERATING_SURFACE == {"coalesce_lines": COALESCE_LINES_RANGE,
+                                 "max_in_flight": MAX_IN_FLIGHT_RANGE}
 
 
 def test_tune_mode_default_off_and_validation(monkeypatch):

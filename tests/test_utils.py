@@ -92,3 +92,30 @@ class TestParseDuration:
     def test_invalid(self, text):
         with pytest.raises(DurationError):
             parse_duration(text)
+
+
+@pytest.mark.parametrize("env,want", [
+    ({"JAX_COMPILATION_CACHE_DIR": "/elsewhere", "JAX_PLATFORMS": "tpu"},
+     None),  # JAX reads the variable itself; nothing is set in code
+    ({"JAX_PLATFORMS": "cpu"}, None),  # a test or rehearsal
+    ({"JAX_PLATFORMS": "tpu"}, "checkout"),
+    ({}, "checkout"),
+])
+def test_compile_cache_dir_placement(monkeypatch, env, want):
+    """JAX's compile cache goes where JAX_COMPILATION_CACHE_DIR says, or
+    else to one fixed directory in the checkout — never a temp, pid or
+    time-stamped path, which no later process would find again."""
+    import os
+
+    from klogs_tpu.utils import cache
+
+    for k in ("JAX_COMPILATION_CACHE_DIR", "JAX_PLATFORMS"):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    got = cache.compile_cache_dir()
+    if want is None:
+        assert got is None
+        return
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert got == os.path.join(repo, ".jax_cache") == cache.JAX_CACHE_DIR

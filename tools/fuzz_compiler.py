@@ -26,7 +26,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")  # hermetic; beats eager TPU plugins
+jax.config.update("jax_platforms", "cpu")  # hermetic: engines compared on the CPU
 
 from klogs_tpu.filters.compiler import (  # noqa: E402
     RegexSyntaxError,
