@@ -427,6 +427,8 @@ async def _run_async_inner(
         term.fatal("%s", e)
     if source is None:
         backend = backend or make_backend(opts)
+    from klogs_tpu.obs import trace as _trace
+
     profiling = False
     if opts.profile:
         # Optional tracing hook (SURVEY.md §5: the reference has none;
@@ -436,6 +438,8 @@ async def _run_async_inner(
         except ImportError as e:
             term.fatal("--profile requires jax: %s", e)
         jax.profiler.start_trace(opts.profile)
+        # The per-group spans annotate the capture, on its clock.
+        _trace.TRACER.device_clock(True)
         profiling = True
         term.info("Profiling to %s", term.green(opts.profile))
     try:
@@ -527,8 +531,6 @@ async def _run_async_inner(
         # spans still feed /traces (--metrics-port sidecar) and the
         # degrade flight recorder. Trace counters ride the run
         # registry when one exists.
-        from klogs_tpu.obs import trace as _trace
-
         if opts.trace_json is not None:
             _trace.TRACER.enable_default()
             _trace.TRACER.set_json_path(opts.trace_json)
@@ -824,6 +826,7 @@ async def _run_async_inner(
         if profiling:
             import jax.profiler
 
+            _trace.TRACER.device_clock(False)
             try:
                 jax.profiler.stop_trace()
             except Exception as e:

@@ -32,8 +32,8 @@ class FilterStats:
     process-global ``obs.REGISTRY`` so the sidecar scrapes live values.
 
     Three latency series are kept separate so saturation diagnosis is
-    possible (the e2e number conflates them):
-    - batch (e2e): sink-observed await, enqueue -> verdicts.
+    possible (the batch number conflates them):
+    - batch: sink-observed await, flush lock taken -> verdicts.
     - queue: enqueue -> device dispatch (coalescing + backpressure wait),
       recorded by AsyncFilterService.
     - device: dispatch -> verdicts fetched, recorded by
@@ -52,6 +52,13 @@ class FilterStats:
         self._batches = r.family("klogs_sink_batches_total")
         self._deadline_flushes = r.family("klogs_sink_deadline_flush_total")
         self._batch = r.family("klogs_sink_batch_latency_seconds")
+        # Where a follow line waits before its batch's verdicts: in the
+        # sink's pending buffer, on the sink's flush lock, and behind a
+        # late event loop (sampled once per deadline-flusher pass).
+        self._pending_wait = r.family("klogs_sink_pending_wait_seconds")
+        self._lock_wait = r.family("klogs_sink_flush_lock_wait_seconds")
+        self._flusher = r.family("klogs_sink_flusher_seconds")
+        self._loop_lag = r.family("klogs_loop_lag_seconds")
         self._queue = r.family("klogs_coalescer_queue_wait_seconds")
         self._device = r.family("klogs_engine_device_batch_seconds")
         # Two-phase (prefilter) visibility: without these a user cannot
@@ -211,6 +218,18 @@ class FilterStats:
         from klogs_tpu.obs.trace import TRACER
 
         self._device.observe(latency_s, exemplar=TRACER.exemplar())
+
+    def record_flush_wait(self, pending_s: float, lock_s: float) -> None:
+        """One flush: its first line's wait in the pending buffer, then
+        the flush's wait for the sink's flush lock."""
+        self._pending_wait.observe(pending_s)
+        self._lock_wait.observe(lock_s)
+
+    def record_flusher_pass(self, lag_s: float, pass_s: float) -> None:
+        """One deadline-flusher pass: how late it woke, how long it
+        took over every live sink."""
+        self._loop_lag.observe(lag_s)
+        self._flusher.observe(pass_s)
 
     def record_deadline_flush(self) -> None:
         """A flush forced by the follow-mode deadline (not batch size)

@@ -112,9 +112,17 @@ class FilteredSink(Sink):
         # downstream (coalescer/shard/RPC/device/write).
         if self._flush_lock is None:
             self._flush_lock = asyncio.Lock()
+        t_request = time.perf_counter()
         with trace.TRACER.span("sink.flush",
                                pending=self._pending_count()):
             async with self._flush_lock:
+                # Read after the lock: another flush may have taken the
+                # lines that were pending at the request.
+                since = self._pending_since
+                if since is not None and self._pending_count():
+                    self._stats.record_flush_wait(
+                        max(0.0, t_request - since),
+                        time.perf_counter() - t_request)
                 await self._flush_pending_locked(final=final)
 
     async def _flush_pending_locked(self, final: bool = False) -> None:
@@ -349,19 +357,27 @@ class FilterPipeline:
         error" — set ``stop`` (graceful stream teardown) and re-raise so
         the awaiter surfaces it, instead of quietly dropping the batch
         of an idle stream that will never write again."""
+        period = self.deadline_s / 2
         while True:
-            await asyncio.sleep(self.deadline_s / 2)
+            t_sleep = time.perf_counter()
+            await asyncio.sleep(period)
+            t_woke = time.perf_counter()
             # Concurrent: a serial sweep over N slow flushes would make
             # the sweep period N x the flush latency (observed: minutes
             # at 200 sinks). With the coalescing service these merge
             # into a handful of device batches anyway. Per-sink fault
             # isolation: one dead SINK (SinkError) must not kill the
             # flusher for every healthy stream — its own worker
-            # surfaces that failure at the next write.
-            results = await asyncio.gather(
-                *[s.flush_if_stale() for s in list(self._live_sinks)],
-                return_exceptions=True,
-            )
+            # surfaces that failure at the next write. The flushes
+            # start outside the pass's span: each stays its own root.
+            flushes = [asyncio.ensure_future(s.flush_if_stale())
+                       for s in list(self._live_sinks)]
+            with trace.TRACER.span("sink.flusher", sinks=len(flushes)):
+                results = await asyncio.gather(*flushes,
+                                               return_exceptions=True)
+            self.stats.record_flusher_pass(
+                max(0.0, t_woke - t_sleep - period),
+                time.perf_counter() - t_woke)
             for r in results:
                 if isinstance(r, Unavailable):
                     term.error("filter service unavailable and "

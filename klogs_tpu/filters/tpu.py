@@ -574,9 +574,9 @@ class NFAEngineFilter(LogFilter):
                     cls = np.frombuffer(buf, dtype=np.int8).reshape(
                         -1, int(w) + 3)
                 self._record_sub_batch(int(w), rows, int(lens[sel].sum()))
-                # device.kernel times the (asynchronous) dispatch
+                # device.enqueue times the (asynchronous) dispatch
                 # enqueue; the round-trip completion is device.fetch.
-                with trace.TRACER.span("device.kernel", width=int(w),
+                with trace.TRACER.span("device.enqueue", width=int(w),
                                        rows=rows):
                     parts.append((sel, *self._match_cls_device(cls)))
         if not bool(short.all()):
@@ -624,7 +624,7 @@ class NFAEngineFilter(LogFilter):
                 lengths = np.zeros(rows, dtype=np.int32)
                 lengths[:len(sel)] = sub_lens
                 self._record_sub_batch(int(w), rows, int(lens[sel].sum()))
-                with trace.TRACER.span("device.kernel", width=int(w),
+                with trace.TRACER.span("device.enqueue", width=int(w),
                                        rows=rows, swept=True):
                     parts.append((sel, *self._match_full(batch, lengths)))
         if not bool(short.all()):

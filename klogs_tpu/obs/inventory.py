@@ -101,8 +101,8 @@ SPECS: dict[str, dict] = {
         "gauge", "This filterd's advertised headroom estimate in "
         "[0, 1], by signal trust: 1 - admitted rate / envelope when "
         "KLOGS_FLEET_CAPACITY_LPS calibrates one, else 1 - peak stage "
-        "utilization from the live profiler, else the committed "
-        "operating-point ceiling. Advertised through Hello; see "
+        "utilization from the live profiler, else not set (Hello "
+        "advertises null). Advertised through Hello; see "
         "docs/OBSERVABILITY.md Fleet telemetry."),
     "klogs_fleet_endpoint_headroom": _m(
         "gauge", "Headroom last advertised by each filterd endpoint's "
@@ -131,11 +131,29 @@ SPECS: dict[str, dict] = {
     "klogs_sink_batches_total": _m(
         "counter", "Filter batches flushed."),
     "klogs_sink_batch_latency_seconds": _m(
-        "histogram", "End-to-end batch latency: enqueue to verdicts, "
-        "sink-observed.", buckets=LATENCY_BUCKETS),
+        "histogram", "Per flush, from the flush lock taken to the "
+        "verdicts back, sink-observed. Leaves out the pending wait "
+        "(klogs_sink_pending_wait_seconds), the lock wait "
+        "(klogs_sink_flush_lock_wait_seconds) and the write.",
+        buckets=LATENCY_BUCKETS),
     "klogs_sink_deadline_flush_total": _m(
         "counter", "Flushes forced by the follow-mode deadline rather "
         "than batch-size."),
+    "klogs_sink_pending_wait_seconds": _m(
+        "histogram", "Per flush, from the first pending line's arrival "
+        "in the sink to the flush request (the deadline or batch-size "
+        "wait).", buckets=LATENCY_BUCKETS),
+    "klogs_sink_flush_lock_wait_seconds": _m(
+        "histogram", "Per flush, from the flush request to taking that "
+        "sink's flush lock (the wait on its previous batch's "
+        "verdicts).", buckets=LATENCY_BUCKETS),
+    "klogs_sink_flusher_seconds": _m(
+        "histogram", "One follow-mode deadline-flusher pass over every "
+        "live sink.", buckets=LATENCY_BUCKETS),
+    "klogs_loop_lag_seconds": _m(
+        "histogram", "Event-loop lag: how late the deadline flusher "
+        "woke past the sleep it asked for, once per pass.",
+        buckets=LATENCY_BUCKETS),
 
     # -- coalescer layer (AsyncFilterService) -------------------------
     "klogs_coalescer_queue_depth": _m(
@@ -306,6 +324,10 @@ SPECS: dict[str, dict] = {
     "klogs_source_bytes_total": _m(
         "counter", "Bytes delivered by non-kube sources, by source "
         "kind (file, archive, socket).", labels=("kind",),
+        bounds={"kind": "enum"}),
+    "klogs_source_reads_total": _m(
+        "counter", "Chunks delivered by non-kube sources, by source "
+        "kind; bytes/reads is the mean read size.", labels=("kind",),
         bounds={"kind": "enum"}),
     "klogs_source_rotations_total": _m(
         "counter", "File rotations/truncations detected by the replay "

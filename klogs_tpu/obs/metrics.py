@@ -25,6 +25,7 @@ Design rules:
   against the same SPECS table.
 """
 
+import bisect
 import random
 import threading
 import time
@@ -142,12 +143,12 @@ class Histogram:
         with self._lock:
             self.count += 1
             self.sum += value
-            hit = len(self.buckets)  # +Inf
-            for i, bound in enumerate(self.buckets):
-                if value <= bound:
-                    self.bucket_counts[i] += 1
-                    hit = i
-                    break
+            # The first bound at or above the value, else +Inf (NaN too).
+            hit = bisect.bisect_left(self.buckets, value)
+            if hit < len(self.buckets) and value <= self.buckets[hit]:
+                self.bucket_counts[hit] += 1
+            else:
+                hit = len(self.buckets)
             if exemplar is not None:
                 self._exemplars[hit] = (exemplar, value, time.time())
             self._reservoir.add(value)

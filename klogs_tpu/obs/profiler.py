@@ -8,7 +8,7 @@ have?* This module closes that gap with two cooperating pieces:
 
 - ``PipelineProfiler`` — folds every finished span whose name is in
   the pipeline stage catalog (PR 9's spans: fanout.read ->
-  coalescer.dispatch -> device.sweep/groupscan/kernel/fetch ->
+  coalescer.dispatch -> device.sweep/groupscan/enqueue/fetch ->
   sink.write -> rpc.client/server ...) into per-stage busy-seconds,
   and on a cheap periodic tick derives rolling per-stage utilization
   (busy-seconds per wall-second over the tick window, unbiased by the
@@ -32,9 +32,7 @@ have?* This module closes that gap with two cooperating pieces:
 Design rules (the obs budget discipline):
 
 - Folding rides the span stream — per-BATCH, never per-line — and is
-  one dict lookup + two float adds per span. The <2% overhead budget
-  on the K=1024 bench path is measured and recorded by
-  ``tools/bench_fleet.py`` (BENCH_FLEET.json ``overhead`` row).
+  one dict lookup + two float adds per span.
 - Utilization is windowed at tick time, not per span; gauges and the
   JSONL line update once per ``KLOGS_PROFILE_INTERVAL_S``.
 - Everything is bounded: the stage catalog is a fixed enum, probes are
@@ -71,7 +69,7 @@ STAGES: "tuple[str, ...]" = (
     "device.frame",
     "device.sweep",
     "device.groupscan",
-    "device.kernel",
+    "device.enqueue",
     "device.fetch",
     "mesh.dispatch",
 )

@@ -6,7 +6,7 @@ and why. This module adds the per-batch story: a dependency-free span
 core instrumenting one batch's full life — fanout read -> sink flush ->
 shard routing (hedge/reroute/failover as events) -> RPC client/server
 (context propagated in gRPC metadata) -> server coalescer -> device
-frame/sweep/kernel/fetch -> sink write — plus a flight recorder that
+frame/sweep/enqueue/fetch -> sink write — plus a flight recorder that
 turns every degrade event into a self-contained JSON artifact.
 
 Design rules (same budget discipline as obs.metrics):
@@ -29,6 +29,13 @@ Design rules (same budget discipline as obs.metrics):
 - **Bounded everything.** Attributes, events, the finished-span ring,
   and the recorder ring all have fixed caps; a runaway trace cannot
   grow process memory.
+
+The device clock (``Tracer.device_clock``): while ``--profile`` captures
+a device trace, every span named in ``CLOCK_STAGES`` (sampled or not)
+and every sampled span also opens a ``jax.profiler.TraceAnnotation``
+for its lifetime, so the capture holds the host stage beside the device
+ops on one clock. An unsampled stage span is then a bare annotation
+(``_ClockSpan``): no ids, no context, nothing recorded here.
 
 The flight recorder (``FlightRecorder``) keeps a fixed ring of recent
 finished spans. ``trigger(reason)`` — fired on breaker open,
@@ -64,6 +71,20 @@ MAX_EVENTS = 64
 DEFAULT_RING = 4096
 
 _SENTINEL = object()  # "parent not given" marker for start_span
+
+# The per-group host stages annotated on the device trace's clock while
+# ``Tracer.device_clock`` is on. Each occurs once per coalesced group,
+# width bucket or flusher pass (hundreds a second), never per chunk.
+CLOCK_STAGES: "tuple[str, ...]" = (
+    "coalescer.dispatch",
+    "device.frame",
+    "device.enqueue",
+    "device.fetch",
+    "device.sweep",
+    "mesh.dispatch",
+    "sink.flusher",
+)
+_CLOCK_SET = frozenset(CLOCK_STAGES)
 
 # Trace/span ids come from a private PRNG (seeded from the OS) so tests
 # that seed the global `random` module cannot collide trace identities.
@@ -148,7 +169,7 @@ class Span:
     __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
                  "sampled", "local_root", "root_span_id", "start_unix",
                  "_t0", "duration_s", "status", "attrs", "events",
-                 "_token", "_ended")
+                 "_token", "_ended", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: int,
                  span_id: int, parent_id: "int | None", sampled: bool,
@@ -176,6 +197,7 @@ class Span:
         self.events: "list[dict[str, object]]" = []
         self._token: "contextvars.Token[object] | None" = None
         self._ended = False
+        self._ann: Any = None
         if sampled and attrs:
             for k, v in attrs.items():
                 self.set_attr(k, v)
@@ -208,6 +230,9 @@ class Span:
             return
         self._ended = True
         self.duration_s = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self.sampled:
             self._tracer._finish(self)
 
@@ -215,6 +240,11 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set(self)
+        annotate = self._tracer._annotate
+        if annotate is not None and (self.sampled
+                                     or self.name in _CLOCK_SET):
+            self._ann = annotate(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type: "type[BaseException] | None",
@@ -281,6 +311,30 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+
+class _ClockSpan(_NoopSpan):
+    """An unsampled ``CLOCK_STAGES`` span while the device clock is on:
+    a no-op span that holds one profiler annotation for its lifetime.
+    It never enters the context var, so it parents nothing."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, annotation: Any) -> None:
+        self._ann = annotation
+
+    def end(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def __enter__(self) -> "_ClockSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self.end()
+
+
 # The active span for the current task/thread. Module-level (contextvars
 # must be created once); shared by every Tracer in the process — in
 # practice one process runs one TRACER, and tests that build private
@@ -306,8 +360,28 @@ class Tracer:
         self._json_lock = threading.Lock()
         self._json_path: "str | None" = None
         self._m_spans: Any = None
+        # jax.profiler.TraceAnnotation while the device clock is on.
+        self._annotate: Any = None
 
     # -- configuration ------------------------------------------------
+
+    def device_clock(self, on: bool) -> None:
+        """Annotate spans on the device trace's clock (module
+        docstring). ``--profile`` turns it on right after
+        ``jax.profiler.start_trace`` and off right before
+        ``stop_trace``; JAX is imported only here."""
+        if on:
+            from jax.profiler import TraceAnnotation
+
+            self._annotate = TraceAnnotation
+        else:
+            self._annotate = None
+
+    def _clock_span(self, name: str) -> "_NoopSpan":
+        """What a span that records nothing is while the clock is on."""
+        if name not in _CLOCK_SET:
+            return NOOP_SPAN
+        return _ClockSpan(self._annotate(name))
 
     def _rate(self) -> float:
         if self._sample is None:
@@ -365,6 +439,7 @@ class Tracer:
             self._json_path = None
         self._sample = sample
         self._m_spans = None
+        self._annotate = None
 
     # -- span creation ------------------------------------------------
 
@@ -374,13 +449,16 @@ class Tracer:
         contextvar); pass an explicit ``SpanContext`` (e.g. extracted
         from gRPC metadata, or a coalesced group's carrying member) or
         ``None`` to force a new root. Returns the no-op singleton when
-        nothing samples — callers never branch."""
+        nothing samples (a ``_ClockSpan`` for a ``CLOCK_STAGES`` name
+        while the device clock is on) — callers never branch."""
         if parent is _SENTINEL:
             parent = _CURRENT.get()
         if parent is None:
             rate = self._rate()
             if rate <= 0.0:
-                return NOOP_SPAN
+                if self._annotate is None:
+                    return NOOP_SPAN
+                return self._clock_span(name)
             sampled = rate >= 1.0 or _IDS.random() < rate
             return Span(self, name, _IDS.getrandbits(128),
                         _IDS.getrandbits(64), None, sampled, attrs or None)
@@ -393,7 +471,9 @@ class Tracer:
         else:
             ctx = parent
         if ctx is None:
-            return NOOP_SPAN
+            if self._annotate is None:
+                return NOOP_SPAN
+            return self._clock_span(name)
         assert isinstance(ctx, SpanContext)
         return Span(self, name, ctx.trace_id, _IDS.getrandbits(64),
                     ctx.span_id, ctx.sampled, attrs or None,

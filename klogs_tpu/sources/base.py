@@ -108,6 +108,7 @@ class SourceMetrics:
     def __init__(self, kind: str) -> None:
         self.kind = kind
         self._bytes: object = None
+        self._reads: object = None
         self._rotations: object = None
         self._members: object = None
         self._errors: object = None
@@ -118,6 +119,8 @@ class SourceMetrics:
             return
         self._bytes = registry.family(
             "klogs_source_bytes_total").labels(kind=self.kind)
+        self._reads = registry.family(
+            "klogs_source_reads_total").labels(kind=self.kind)
         self._rotations = registry.family("klogs_source_rotations_total")
         self._members = registry.family(
             "klogs_source_archive_members_total")
@@ -126,8 +129,10 @@ class SourceMetrics:
         self._conns = registry.family("klogs_source_connections_total")
 
     def add_bytes(self, n: int) -> None:
+        """One chunk of ``n`` bytes delivered downstream."""
         if self._bytes is not None:
             self._bytes.inc(n)  # type: ignore[attr-defined]
+            self._reads.inc()  # type: ignore[attr-defined]
 
     def rotation(self) -> None:
         if self._rotations is not None:

@@ -91,7 +91,72 @@ class TestFilteredSink:
         assert bytes(inner.data) == b"keep tail-no-newline"
 
 
+def _hist(stats, name):
+    """(observations, sum) of one unlabeled histogram of ``stats``."""
+    _, total, count = stats.registry.family(name)._default().snapshot()
+    return count, total
+
+
+class _SlowService:
+    """A filter service whose verdicts take ``delay_s`` (all kept)."""
+
+    def __init__(self, delay_s):
+        self.delay_s = delay_s
+
+    async def match(self, lines):
+        await asyncio.sleep(self.delay_s)
+        return [True] * len(lines)
+
+
+class TestFlushWaits:
+    def test_pending_and_lock_waits_once_per_flush(self):
+        """Each flush observes its first line's pending wait and its wait
+        on the sink's flush lock, once; the second of two flushes of one
+        sink waits on the lock for the first one's slow verdicts."""
+        inner = _MemSink()
+        stats = FilterStats()
+        sink = FilteredSink(inner, None, stats, batch_lines=2,
+                            service=_SlowService(0.2))
+
+        async def scenario():
+            await sink.write(b"a\n")
+            await asyncio.sleep(0.02)  # a waits in the pending buffer
+            first = asyncio.create_task(sink.write(b"b\n"))  # flush 1
+            await asyncio.sleep(0.005)  # flush 1 holds the lock
+            await sink.write(b"c\nd\n")  # flush 2 waits on it
+            await first
+            await sink.close()  # nothing pending: no flush to observe
+
+        asyncio.run(scenario())
+        assert bytes(inner.data) == b"a\nb\nc\nd\n"
+        n_pending, pending_s = _hist(stats, "klogs_sink_pending_wait_seconds")
+        n_lock, lock_s = _hist(stats, "klogs_sink_flush_lock_wait_seconds")
+        assert n_pending == n_lock == stats.batches == 2
+        assert pending_s >= 0.02  # flush 1's first line waited 20 ms
+        assert 0.1 <= lock_s < 2.0  # flush 2 waited out flush 1
+
+
 class TestDeadlineFlusher:
+    def test_flusher_records_one_lag_and_pass_per_pass(self):
+        from klogs_tpu.filters.sink import make_pipeline
+
+        pipeline = make_pipeline(["ERROR"], "cpu", deadline_s=0.02)
+
+        async def scenario():
+            flusher = asyncio.create_task(pipeline.run_deadline_flusher())
+            await asyncio.sleep(0.105)  # passes at ~10, 20, ... 100 ms
+            flusher.cancel()
+            try:
+                await flusher
+            except asyncio.CancelledError:
+                pass
+
+        asyncio.run(scenario())
+        n_lag, lag_s = _hist(pipeline.stats, "klogs_loop_lag_seconds")
+        n_pass, pass_s = _hist(pipeline.stats, "klogs_sink_flusher_seconds")
+        assert 3 <= n_lag == n_pass <= 10
+        assert lag_s >= 0.0 and pass_s >= 0.0
+
     def test_quiet_stream_flushes_within_deadline(self, tmp_path):
         """A matching line from a container that then goes quiet must hit
         the file within ~deadline_s, without waiting for batch_lines."""

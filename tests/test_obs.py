@@ -98,6 +98,23 @@ def test_registry_threaded_increments_are_exact():
     assert sum(ch.value for _, ch in fam.children()) == N * M
 
 
+def test_histogram_bucket_is_the_first_bound_at_or_above():
+    from klogs_tpu.obs.metrics import LATENCY_BUCKETS, Histogram
+
+    bounds = LATENCY_BUCKETS
+    values = [0.0, *bounds, *(b * 0.999 for b in bounds),
+              *(b * 1.001 for b in bounds), 1e9, float("nan")]
+    h = Histogram(bounds)
+    for v in values:
+        h.observe(v)
+    want = [sum(1 for v in values
+                if v <= b and all(v > c for c in bounds[:i]))
+            for i, b in enumerate(bounds)]
+    counts, _, n = h.snapshot()
+    assert counts == want and n == len(values)
+    assert sum(counts) == n - sum(1 for v in values if not v <= bounds[-1])
+
+
 # -- exposition -------------------------------------------------------
 
 def test_prometheus_exposition_golden():

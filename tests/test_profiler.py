@@ -502,3 +502,10 @@ def test_profiler_folds_stages_across_real_hop():
                   "device.fetch"):
         assert stage in doc["stages"], (stage, sorted(doc["stages"]))
         assert doc["stages"][stage]["spans"] >= 1
+
+
+def test_stage_catalog_names_the_enqueue_span():
+    """The asynchronous dispatch enqueue is folded as device.enqueue;
+    the device's own time is the device trace's to give."""
+    assert "device.enqueue" in STAGES
+    assert not [s for s in STAGES if s.endswith(".kernel")]

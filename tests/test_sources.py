@@ -678,3 +678,38 @@ def test_replay_open_failure_does_not_leak_fd(tmp_path, monkeypatch):
     with pytest.raises(OSError, match="injected"):
         stream._open_file()
     assert len(opened) == 1 and opened[0].closed
+
+
+def test_socket_counts_one_read_per_delivered_chunk():
+    """klogs_source_reads_total{kind=socket} advances once per chunk the
+    stream delivers, beside the bytes counter: bytes/reads is the mean
+    read size."""
+    from klogs_tpu.obs.metrics import Registry
+
+    registry = Registry()
+
+    async def scenario():
+        src = SocketSource("127.0.0.1:0", max_conns=4)
+        src.bind_registry(registry)
+        await src.start()
+        _r, w = await asyncio.open_connection("127.0.0.1", src.bound_port())
+        await asyncio.sleep(0.1)
+        (ref,) = await src.discover()
+        stream = await src.open_stream(ref, LogOptions(follow=True))
+        chunks = []
+        for part in (b"one\n", b"two\nthree\n"):
+            w.write(part)
+            await w.drain()
+            chunks.append(await stream.__anext__())
+        w.close()
+        await w.wait_closed()
+        chunks.append(b"".join([c async for c in stream]))
+        await src.close()
+        return chunks
+
+    chunks = run(scenario())
+    assert chunks == [b"one\n", b"two\nthree\n", b""]
+    reads = registry.family("klogs_source_reads_total").labels(kind="socket")
+    sent = registry.family("klogs_source_bytes_total").labels(kind="socket")
+    assert reads.value == 2  # EOF delivers nothing and counts nothing
+    assert sent.value == len(b"one\ntwo\nthree\n")

@@ -666,3 +666,143 @@ def test_metrics_endpoint_exemplars_only_on_opt_in():
     plain, rich = run(scenario())
     assert "# {" not in plain
     assert '# {trace_id="' in rich
+
+
+# -- the device clock (--profile) ---------------------------------------
+
+
+class _Annotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter/exit."""
+
+    log: "list[tuple[str, str]]" = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _Annotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _Annotation.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def annotation(monkeypatch):
+    import jax.profiler
+
+    _Annotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    yield _Annotation.log
+    trace.TRACER.device_clock(False)
+
+
+def test_device_clock_off_is_the_noop_singleton_for_every_name(annotation):
+    trace.reset(0.0)
+    for name in trace.CLOCK_STAGES + ("sink.flush", "anything"):
+        sp = trace.TRACER.span(name)
+        assert sp is trace.NOOP_SPAN
+        with sp:
+            pass
+    trace.TRACER.device_clock(True)
+    trace.TRACER.device_clock(False)
+    with trace.TRACER.span("coalescer.dispatch") as sp:
+        assert sp is trace.NOOP_SPAN
+    assert annotation == []
+
+
+def test_device_clock_annotates_stages_sampled_or_not(annotation):
+    trace.reset(0.0)
+    trace.TRACER.device_clock(True)
+    for name in trace.CLOCK_STAGES:
+        with trace.TRACER.span(name, rows=8):
+            pass
+    # Not a clock stage, not sampled: nothing.
+    assert trace.TRACER.span("sink.flush") is trace.NOOP_SPAN
+    assert annotation == [(e, n) for n in trace.CLOCK_STAGES
+                          for e in ("enter", "exit")]
+    annotation.clear()
+    # An unsampled head decision carried by a context: a real (unsampled)
+    # span, annotated only under a clock-stage name.
+    unsampled = trace.SpanContext(1, 2, sampled=False)
+    with trace.TRACER.span("device.fetch", parent=unsampled):
+        pass
+    with trace.TRACER.span("sink.write", parent=unsampled):
+        pass
+    assert annotation == [("enter", "device.fetch"), ("exit", "device.fetch")]
+    annotation.clear()
+    # Sampled: every name annotates, nested as the spans are.
+    trace.TRACER.configure(1.0)
+    with trace.TRACER.span("sink.flush"):
+        with trace.TRACER.span("device.frame"):
+            pass
+    assert annotation == [("enter", "sink.flush"), ("enter", "device.frame"),
+                          ("exit", "device.frame"), ("exit", "sink.flush")]
+    assert {d["name"] for d in trace.TRACER.finished_spans()} == {
+        "sink.flush", "device.frame"}
+
+
+def test_unsampled_clock_span_leaves_context_and_ring_alone(annotation):
+    trace.reset(0.0)
+    trace.TRACER.device_clock(True)
+    sp = trace.TRACER.span("coalescer.dispatch", members=3)
+    assert sp is not trace.NOOP_SPAN and not sp.sampled
+    assert sp.context() is None
+    with sp:
+        assert trace._CURRENT.get() is None
+        assert trace.TRACER.current_span() is None
+        assert trace.TRACER.inject() == ()
+        # A child under it is what it would be without it.
+        assert trace.TRACER.span("sink.write") is trace.NOOP_SPAN
+        sp.set_attr("k", "v")
+        sp.add_event("e")
+    assert trace.TRACER.finished_spans() == []
+    assert annotation == [("enter", "coalescer.dispatch"),
+                          ("exit", "coalescer.dispatch")]
+
+
+def test_reset_turns_the_device_clock_off(annotation):
+    trace.TRACER.device_clock(True)
+    trace.reset(0.0)
+    assert trace.TRACER.span("device.fetch") is trace.NOOP_SPAN
+
+
+def test_clock_spans_land_on_the_host_plane_of_a_capture(tmp_path):
+    """One real CPU capture: two overlapping, non-nested clock spans on
+    one event-loop thread each keep their own start and duration on the
+    /host:CPU plane, on the clock of the capture's profile_start_time."""
+    import glob
+
+    import jax.profiler
+    from jax.profiler import ProfileData
+
+    trace.reset(0.0)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+
+    async def group(delay_s, hold_s):
+        await asyncio.sleep(delay_s)
+        with trace.TRACER.span("coalescer.dispatch"):
+            await asyncio.sleep(hold_s)
+
+    async def both():
+        await asyncio.gather(group(0.0, 0.05), group(0.02, 0.08))
+
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        trace.TRACER.device_clock(True)
+        run(both())
+    finally:
+        trace.TRACER.device_clock(False)
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    planes = {p.name: p for p in ProfileData.from_file(path).planes}
+    found = sorted((e.start_ns, e.duration_ns)
+                   for line in planes["/host:CPU"].lines
+                   for e in line.events if e.name == "coalescer.dispatch")
+    assert len(found) == 2
+    (s1, d1), (s2, d2) = found
+    assert 0.045e9 <= d1 < 1e9 and 0.075e9 <= d2 < 1e9
+    assert s1 < s2 < s1 + d1 < s2 + d2  # overlapping, not nested
+    assert "profile_start_time" in dict(planes["Task Environment"].stats)
