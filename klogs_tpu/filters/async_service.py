@@ -19,6 +19,15 @@ Two problems it solves:
    callers are; p99 latency gains the coalesce window (few ms) and
    loses the queueing collapse.
 
+   A group closes when the coalesce window ends or when it reaches
+   ``coalesce_lines``, which is a CEILING: a caller whose lines would
+   carry the pending group past it closes the group first and starts
+   the next one (a single caller larger than the ceiling goes alone).
+   The engine pads a group's rows up to a power of two
+   (filters/tpu._bucket_batch), so the default ceiling is one such
+   bucket, 16,384 rows: a full-size group fills the bucket it is
+   padded to.
+
 Per-sink write ordering is the sink's concern (FilteredSink holds its
 flush lock across the await); cross-sink batches merge and overlap
 freely. In-flight device work is bounded (backpressure).
@@ -47,7 +56,7 @@ from klogs_tpu.utils.env import warn_positive_int as _env_int
 
 DEFAULT_MAX_IN_FLIGHT = _env_int("KLOGS_MAX_IN_FLIGHT", 16)
 DEFAULT_FETCH_WORKERS = _env_int("KLOGS_FETCH_WORKERS", 8)
-DEFAULT_COALESCE_LINES = _env_int("KLOGS_COALESCE_LINES", 8192)
+DEFAULT_COALESCE_LINES = _env_int("KLOGS_COALESCE_LINES", 16384)
 DEFAULT_COALESCE_DELAY_S = 0.005
 
 # Offsets ride int32: a coalesced group whose combined payload passes
@@ -81,6 +90,7 @@ class AsyncFilterService:
                 "depth": r.family("klogs_coalescer_queue_depth"),
                 "pending": r.family("klogs_coalescer_pending_lines"),
                 "groups": r.family("klogs_coalescer_groups_total"),
+                "cap_closes": r.family("klogs_coalescer_cap_closes_total"),
                 "members": r.family("klogs_coalescer_group_members"),
                 "lines": r.family("klogs_coalescer_group_lines"),
                 "splits": r.family("klogs_coalescer_group_splits_total"),
@@ -236,6 +246,12 @@ class AsyncFilterService:
             raise RuntimeError("AsyncFilterService is closed")
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
+        if self._pending and self._pending_lines + n > self._coalesce_lines:
+            # Kick before overflow: the pending group goes as it is and
+            # this caller opens the next one.
+            if self._m is not None:
+                self._m["cap_closes"].inc()
+            self._kick(loop)
         # The caller's span context rides the pending entry: the
         # coalesced group's dispatch span parents under the FIRST
         # caller's trace (one trace carries the full downstream story)

@@ -171,6 +171,9 @@ SPECS: dict[str, dict] = {
     "klogs_coalescer_group_lines": _m(
         "histogram", "Lines per coalesced group.",
         buckets=GROUP_LINE_BUCKETS),
+    "klogs_coalescer_cap_closes_total": _m(
+        "counter", "Groups closed because the next caller batch would "
+        "have carried them past coalesce_lines."),
     "klogs_coalescer_group_splits_total": _m(
         "counter", "Groups split because the combined payload would "
         "exceed int32 offsets (2 GiB)."),

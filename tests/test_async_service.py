@@ -19,6 +19,7 @@ class SlowFilter(LogFilter):
     def __init__(self, fetch_delay_s: float = 0.05):
         self.fetch_delay_s = fetch_delay_s
         self.dispatched = 0
+        self.group_lines = []  # lines per dispatched group, in order
         self.in_flight_peak = 0
         self._in_flight = 0
         self._lock = threading.Lock()
@@ -28,6 +29,7 @@ class SlowFilter(LogFilter):
 
     def dispatch(self, lines):
         self.dispatched += 1
+        self.group_lines.append(len(lines))
         with self._lock:
             self._in_flight += 1
             self.in_flight_peak = max(self.in_flight_peak, self._in_flight)
@@ -120,6 +122,75 @@ def test_coalesce_size_trigger_flushes_immediately():
     res = asyncio.run(main())
     assert all(r == [True, False] for r in res)
     svc.close()
+
+
+def test_coalesce_lines_is_a_ceiling():
+    # 3-line callers under an 8-line cap: the third caller would carry
+    # the group to 9, so the group closes at 6 first.
+    filt = SlowFilter(fetch_delay_s=0.01)
+    svc = AsyncFilterService(filt, coalesce_lines=8, coalesce_delay_s=0.05)
+
+    def lines(i):
+        return [f"keep {i}".encode(), b"x", b"keep" if i % 2 else b"y"]
+
+    async def main():
+        return await asyncio.gather(*[svc.match(lines(i))
+                                      for i in range(10)])
+
+    res = asyncio.run(main())
+    assert res == [[True, False, bool(i % 2)] for i in range(10)]
+    assert filt.group_lines == [6] * 5
+    svc.close()
+
+
+def test_member_over_the_ceiling_goes_alone():
+    filt = SlowFilter(fetch_delay_s=0.01)
+    svc = AsyncFilterService(filt, coalesce_lines=8, coalesce_delay_s=10.0)
+
+    async def main():
+        small = asyncio.ensure_future(svc.match([b"keep", b"x", b"y"]))
+        await asyncio.sleep(0)
+        # 3 + 10 > 8: the pending 3 go first, then the 10 at once; the
+        # 10 alone would pass the cap too, so neither waits on the timer.
+        big = await asyncio.wait_for(
+            svc.match([b"keep"] * 10), timeout=2.0)
+        return await asyncio.wait_for(small, timeout=2.0), big
+
+    small, big = asyncio.run(main())
+    assert small == [True, False, False] and big == [True] * 10
+    assert filt.group_lines == [3, 10]
+    svc.close()
+
+
+def test_cap_closes_counts_only_groups_closed_before_overflow():
+    stats = FilterStats()
+    filt = SlowFilter(fetch_delay_s=0.001)
+    svc = AsyncFilterService(filt, coalesce_lines=8, coalesce_delay_s=0.02,
+                             stats=stats)
+
+    async def burst(n_callers, n_lines):
+        await asyncio.gather(*[svc.match([b"keep"] * n_lines)
+                               for _ in range(n_callers)])
+
+    async def main():
+        await burst(1, 3)   # timer close
+        await burst(4, 2)   # lands on the cap: size close
+        await burst(1, 10)  # one caller over the cap: size close
+        await burst(3, 3)   # 3 + 3, then 3 would pass 8: one cap close
+        await svc.aclose()
+
+    asyncio.run(main())
+    assert filt.group_lines == [3, 8, 10, 6, 3]
+    reg = stats.registry
+    assert reg.family("klogs_coalescer_cap_closes_total").value == 1
+    assert reg.family("klogs_coalescer_groups_total").value == 5
+
+
+def test_default_ceiling_is_a_row_bucket():
+    from klogs_tpu.filters.async_service import DEFAULT_COALESCE_LINES
+    from klogs_tpu.filters.tpu import _bucket_batch
+
+    assert _bucket_batch(DEFAULT_COALESCE_LINES) == DEFAULT_COALESCE_LINES
 
 
 def test_sink_ordering_with_racing_flushes():
