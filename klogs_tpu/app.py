@@ -566,7 +566,14 @@ async def _run_async_inner(
 
         pipeline = make_pipeline_for(opts, registry=obs_registry)
         inner_factory = make_inner_sink_factory(opts)
+        loop_prof = None
         try:
+            if obs_registry is not None:
+                # The event loop's own accounting: stage samples and
+                # thread CPU, read from outside the loop.
+                from klogs_tpu.obs.loopprof import LoopProfiler
+
+                loop_prof = LoopProfiler(obs_registry).start()
             if PROFILER.enabled:
                 # Started inside this try so the finally below always
                 # reaps the ticker (a fatal during pipeline start must
@@ -792,6 +799,8 @@ async def _run_async_inner(
             # reported, yet scripts still see the conventional 130.
             return 130 if interrupted else 0
         finally:
+            if loop_prof is not None:
+                loop_prof.stop()
             # Close inside the loop even on error/Ctrl-C paths — an
             # unawaited grpc channel or in-flight batch task would be
             # destroyed pending at loop teardown.

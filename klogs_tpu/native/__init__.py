@@ -1,7 +1,9 @@
 """Native host-ops loader: compile-on-first-use with Python fallback.
 
-The extension is a single C file with no dependencies beyond CPython;
-building it is one cc invocation, done lazily and cached next to the
+Each extension is a single C file with no dependencies beyond CPython
+(``_hostops.c``, the host ops; ``_loopsampler.c``, the clock of the
+event-loop sampler in obs/loopprof.py, built on first use); building
+one is one cc invocation, done lazily and cached next to the
 source — or, when the package directory is not writable (installed
 site-packages owned by root, or the single-file klogs.pyz zipapp where
 the "directory" is inside a zip), under ``~/.cache/klogs-tpu`` keyed by
@@ -24,13 +26,18 @@ _SRC = os.path.join(_DIR, "_hostops.c")
 _EXT = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
 
 hostops = None
+# The event-loop sampler's clock (_loopsampler.c), a module of its own
+# because it reads the interpreter's internal layout; built and loaded
+# on first use by load_loopsampler().
+loopsampler = None
+_loopsampler_tried = False
 
 
-def _read_source() -> "bytes | None":
+def _read_source(stem: str = "_hostops") -> "bytes | None":
     """C source bytes — from the filesystem, or from inside the zipapp
     via the package loader when there is no real file."""
     try:
-        with open(_SRC, "rb") as f:
+        with open(os.path.join(_DIR, f"{stem}.c"), "rb") as f:
             return f.read()
     except OSError:
         pass
@@ -38,16 +45,16 @@ def _read_source() -> "bytes | None":
         import importlib.resources
 
         return (importlib.resources.files(__package__)
-                .joinpath("_hostops.c").read_bytes())
+                .joinpath(f"{stem}.c").read_bytes())
     except Exception:
         return None
 
 
-def _cache_path(src: bytes) -> str:
+def _cache_path(src: bytes, stem: str = "_hostops") -> str:
     from klogs_tpu.utils.cache import cache_dir
 
     tag = hashlib.sha256(src).hexdigest()[:16]
-    return os.path.join(cache_dir(), f"_hostops-{tag}{_EXT}")
+    return os.path.join(cache_dir(), f"{stem}-{tag}{_EXT}")
 
 
 def _build(c_src: str, so_path: str) -> bool:
@@ -82,23 +89,24 @@ def build(so_path: str) -> bool:
     return _build(_SRC, so_path)
 
 
-def _ensure_so() -> "str | None":
+def _ensure_so(stem: str = "_hostops") -> "str | None":
     """Path to an up-to-date compiled extension, building if needed.
     Preference order: next to the source (repo checkouts — mtime keeps
     it fresh), else the user cache keyed by source hash (read-only
     installs and zipapps)."""
-    in_tree = os.path.join(_DIR, f"_hostops{_EXT}")
-    src_exists = os.path.exists(_SRC)
+    c_src = os.path.join(_DIR, f"{stem}.c")
+    in_tree = os.path.join(_DIR, f"{stem}{_EXT}")
+    src_exists = os.path.exists(c_src)
     if src_exists and os.path.exists(in_tree) and (
-            os.path.getmtime(_SRC) <= os.path.getmtime(in_tree)):
+            os.path.getmtime(c_src) <= os.path.getmtime(in_tree)):
         return in_tree
     if src_exists and os.access(_DIR, os.W_OK):
-        return in_tree if _build(_SRC, in_tree) else None
+        return in_tree if _build(c_src, in_tree) else None
     # Read-only package (or zipapp): build into the user cache.
-    src = _read_source()
+    src = _read_source(stem)
     if src is None:
         return None
-    cached = _cache_path(src)
+    cached = _cache_path(src, stem)
     if os.path.exists(cached):
         return cached
     try:
@@ -131,19 +139,40 @@ def _load():
     if so is None:
         return
     try:
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "klogs_tpu.native._hostops", so)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        hostops = mod
+        hostops = _import(so, "_hostops")
     except Exception as e:
         hostops = None
         if forced:
             raise RuntimeError(
                 f"KLOGS_NATIVE_SO={forced!r} could not be loaded: {e}"
             ) from e
+
+
+def _import(so: str, stem: str):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        f"klogs_tpu.native.{stem}", so)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_loopsampler():
+    """The loop sampler's module, built and loaded on the first call;
+    None under KLOGS_NO_NATIVE or where it cannot be built or loaded."""
+    global loopsampler, _loopsampler_tried
+    from klogs_tpu.utils.env import read as _env_read
+
+    if not _loopsampler_tried and not _env_read("KLOGS_NO_NATIVE"):
+        _loopsampler_tried = True
+        so = _ensure_so("_loopsampler")
+        if so is not None:
+            try:
+                loopsampler = _import(so, "_loopsampler")
+            except Exception:
+                loopsampler = None
+    return loopsampler
 
 
 _load()

@@ -154,6 +154,24 @@ SPECS: dict[str, dict] = {
         "histogram", "Event-loop lag: how late the deadline flusher "
         "woke past the sleep it asked for, once per pass.",
         buckets=LATENCY_BUCKETS),
+    # The collector's event-loop thread (obs/loopprof.py), read from a
+    # sampler thread: nothing is timed on the loop's own path.
+    "klogs_loop_samples_total": _m(
+        "counter", "Samples of the collector's event-loop thread, 97 a "
+        "second, by the stage of the innermost listed frame on its "
+        "stack (obs.loopprof.TABLE): read, frame, flush, dispatch, "
+        "resume, flusher, poll (blocked in the selector), other.",
+        labels=("stage",), bounds={"stage": "enum"}),
+    "klogs_loop_cpu_seconds_total": _m(
+        "counter", "CPU seconds of the collector's event-loop thread by "
+        "mode (user, sys), from the kernel's per-thread accounting; "
+        "read once a second and at each /metrics scrape and "
+        "--stats-json dump.", labels=("mode",), bounds={"mode": "enum"}),
+    "klogs_process_cpu_seconds_total": _m(
+        "counter", "CPU seconds of the whole collector process, every "
+        "thread, by mode (user, sys), from getrusage; read with "
+        "klogs_loop_cpu_seconds_total.", labels=("mode",),
+        bounds={"mode": "enum"}),
 
     # -- coalescer layer (AsyncFilterService) -------------------------
     "klogs_coalescer_queue_depth": _m(

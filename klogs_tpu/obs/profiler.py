@@ -142,13 +142,18 @@ def process_rss_bytes() -> int:
 
 def refresh_process_metrics(registry: "Registry | None") -> None:
     """Update the process-level gauges (klogs_process_uptime_seconds /
-    klogs_process_rss_bytes) so headroom math and dashboards need no
+    klogs_process_rss_bytes) and the event loop's CPU counters
+    (obs/loopprof.py) so headroom math and dashboards need no
     node exporter. Called before each /metrics render (off the event
     loop), at --stats-json dump time, and on every profiler tick."""
     if registry is None:
         return
     registry.family("klogs_process_uptime_seconds").set(process_uptime_s())
     registry.family("klogs_process_rss_bytes").set(process_rss_bytes())
+    # The event loop's CPU counters, read at the same instant.
+    from klogs_tpu.obs import loopprof
+
+    loopprof.refresh(registry)
 
 
 class PipelineProfiler:
